@@ -80,7 +80,7 @@ from ..index.spgemm import (spgemm_cross_slab, spgemm_join_self,
                             spgemm_self_slab)
 from ..index.store import SignatureIndex
 from ..obs import span, trace_sentinel
-from ..util import next_pow2, shard_map_compat
+from ..util import next_pow2
 
 JOIN_IMPLS = ("spgemm", "legacy")
 
@@ -113,8 +113,9 @@ def _emit_sharded_cached(devices: tuple, axis_name: str, cap: int):
     def shard_fn(offs, ids):
         return spgemm_self_slab(offs[0], ids[0], cap=cap)
 
-    return jax.jit(shard_map_compat(
-        shard_fn, mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax)))
+    return jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax),
+        check_vma=False))
 
 
 def _emit_sharded_fn(mesh, axis_name: str, cap: int):
@@ -135,8 +136,9 @@ def _emit_cross_sharded_cached(devices: tuple, axis_name: str, cap: int):
         return spgemm_cross_slab(dk[0], do[0], di[0], rk[0], ro[0], ri[0],
                                  cap=cap)
 
-    return jax.jit(shard_map_compat(
-        shard_fn, mesh, in_specs=(P(ax),) * 6, out_specs=P(ax)))
+    return jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(P(ax),) * 6, out_specs=P(ax),
+        check_vma=False))
 
 
 def _shard_caps(part: BucketPartition) -> np.ndarray:

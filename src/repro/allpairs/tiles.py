@@ -202,7 +202,6 @@ def _sharded_wave_fns(devices: tuple):
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from ..util import shard_map_compat
     mesh = Mesh(np.array(devices), ("wave",))
     ax = "wave"
 
@@ -212,22 +211,24 @@ def _sharded_wave_fns(devices: tuple):
     def sw_fn(ids_dev, lens_dev, pi, pj, *, Lq: int, Lr: int,
               dp_kernel: str = "wavefront", gap_mode: str = "linear",
               gap_open: int | None = None, gap_extend: int | None = None):
-        f = shard_map_compat(
+        f = jax.shard_map(
             lambda i, l, a, b: sw_gather_scores(
                 i, l, i, l, a, b, Lq=Lq, Lr=Lr, dp_kernel=dp_kernel,
                 gap_mode=gap_mode, gap_open=gap_open,
                 gap_extend=gap_extend),
-            mesh, in_specs=(P(), P(), P(ax), P(ax)), out_specs=P(ax))
+            mesh=mesh, in_specs=(P(), P(), P(ax), P(ax)), out_specs=P(ax),
+            check_vma=False)
         return f(ids_dev, lens_dev, pi, pj)
 
     @functools.partial(jax.jit, static_argnames=("x", "Lq", "Lr"))
     @trace_sentinel("wave_ungapped_spmd", static_key=(devices,))
     def ungapped_fn(ids_dev, lens_dev, pi, pj, *, x: int | None,
                     Lq: int, Lr: int):
-        f = shard_map_compat(
+        f = jax.shard_map(
             lambda i, l, a, b: ungapped_xdrop_scores(
                 gather_rows(i, l, a, Lq), gather_rows(i, l, b, Lr), x=x),
-            mesh, in_specs=(P(), P(), P(ax), P(ax)), out_specs=P(ax))
+            mesh=mesh, in_specs=(P(), P(), P(ax), P(ax)), out_specs=P(ax),
+            check_vma=False)
         return f(ids_dev, lens_dev, pi, pj)
 
     return sw_fn, ungapped_fn
@@ -294,9 +295,8 @@ def _score_block(qm, rm, kind: str, x: int | None, use_pallas: bool,
     if use_pallas:
         from ..kernels import ops
         if kind == "ungapped":
-            return ops.ungapped_wave_scores(
-                qm, rm, x=2**30 if x is None else x,
-                interpret=cfg.pallas_interpret)
+            return ops.ungapped_wave_scores(qm, rm, x=x,
+                                            interpret=cfg.pallas_interpret)
         if cfg.dp_kernel == "wavefront":
             return ops.wavefront_scores(
                 qm, rm, gap_mode=cfg.gap_mode, gap_open=cfg.gap_open,

@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..util import shard_map_compat
-
 from .hamming import hamming_distance
 
 
@@ -211,10 +209,10 @@ def distributed_flip_join(q_sigs, r_sigs, q_ids, r_ids, *, f: int, d: int,
         pairs, total = reduce_join(k2, p2, max_pairs=cfg.max_pairs_per_shard)
         return pairs, total[None], dropped[None]
 
-    fn = shard_map_compat(
-        shard_fn, mesh,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax)),
-        out_specs=(P(ax), P(ax), P(ax)),
+        out_specs=(P(ax), P(ax), P(ax)), check_vma=False,
     )
     return fn(q_sigs, r_sigs, q_ids, r_ids)
 
@@ -264,9 +262,9 @@ def ring_sweep(q_sigs, r_sigs, *, d: int, mesh, axis_name: str = "data",
         q_ids = jnp.arange(q_sigs.shape[0], dtype=jnp.int32)
     if r_ids is None:
         r_ids = jnp.arange(r_sigs.shape[0], dtype=jnp.int32)
-    fn = shard_map_compat(
-        shard_fn, mesh,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name), P(axis_name)),
-        out_specs=(P(axis_name), P(axis_name)),
+        out_specs=(P(axis_name), P(axis_name)), check_vma=False,
     )
     return fn(q_sigs, r_sigs, q_ids, r_ids)

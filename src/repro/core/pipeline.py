@@ -17,6 +17,7 @@ silently losing pairs (DESIGN.md §5 "no silent caps").
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import NamedTuple
 
@@ -26,6 +27,26 @@ import jax.numpy as jnp
 from . import simhash
 from .join import band_join, flip_join
 from .hamming import threshold_pairs
+
+SIG_BLOCK = 4096    # rows per signature-generation block
+
+
+def _row_blocks(fn, ids, lengths, block: int = SIG_BLOCK):
+    """``fn(ids, lengths)`` over row blocks of at most ``block`` rows, as
+    one ``lax.map`` program. Both jobs-1 passes are per row, so blocking
+    changes no result; it bounds the working set at one block's
+    (rows, shingles, f) contributions — a 454,401-row Swiss-Prot-sized
+    reference set in one piece asks a TPU for ~190 GB."""
+    n = ids.shape[0]
+    if n <= block:
+        return fn(ids, lengths)
+    nb = -(-n // block)
+    pad = nb * block - n
+    ids = jnp.pad(ids, ((0, pad), (0, 0)))
+    lengths = jnp.pad(lengths, (0, pad))        # length 0: no shingles
+    out = jax.lax.map(lambda a: fn(*a), (ids.reshape(nb, block, -1),
+                                         lengths.reshape(nb, block)))
+    return out.reshape(nb * block, *out.shape[2:])[:n]
 
 
 @dataclass(frozen=True)
@@ -59,11 +80,13 @@ class SearchResult(NamedTuple):
 class ScalLoPS:
     def __init__(self, cfg: LSHConfig):
         self.cfg = cfg
-        self._sig_fn = jax.jit(
-            lambda ids, lens: simhash.signatures(
-                ids, lens, k=cfg.k, T=cfg.T, f=cfg.f,
-                scheme=cfg.scheme, method=cfg.siggen_method)
-        )
+        self._sig_fn = jax.jit(functools.partial(
+            _row_blocks, functools.partial(
+                simhash.signatures, k=cfg.k, T=cfg.T, f=cfg.f,
+                scheme=cfg.scheme, method=cfg.siggen_method)))
+        self._count_fn = jax.jit(functools.partial(
+            _row_blocks, functools.partial(
+                simhash.feature_counts, k=cfg.k, T=cfg.T)))
 
     # ---- job 1: Signature Generator (map-only) ----
     def signatures(self, ids, lengths):
@@ -72,9 +95,7 @@ class ScalLoPS:
     def feature_counts(self, ids, lengths):
         """Per-sequence neighbour-feature counts (0 => degenerate
         all-ones signature; the paper filters those, §5.2)."""
-        return simhash.feature_counts(jnp.asarray(ids),
-                                      jnp.asarray(lengths),
-                                      k=self.cfg.k, T=self.cfg.T)
+        return self._count_fn(jnp.asarray(ids), jnp.asarray(lengths))
 
     # ---- job 2: Signature Processor ----
     def search(self, q_sigs, r_sigs, *, max_pairs: int | None = None,

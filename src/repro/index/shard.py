@@ -67,7 +67,6 @@ from jax.sharding import PartitionSpec as P
 from ..core.hamming import hamming_distance
 from ..obs import span, trace_sentinel
 from ..obs.trace import record as record_span
-from ..util import shard_map_compat
 from .partition import pad_slabs_pow2
 from .service import BIG, _dedup_candidates, _probe_csr_positions
 from .store import SignatureIndex
@@ -179,10 +178,10 @@ def _ring_program(devices: tuple, axis_name: str, Bl: int, cap: int, k: int,
         return bid, bd, msz[None]
 
     n_args = 10 if has_delta else 6
-    return jax.jit(shard_map_compat(
-        shard_fn, mesh,
+    return jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=tuple(P(ax) for _ in range(n_args)),
-        out_specs=(P(ax), P(ax), P(ax)),
+        out_specs=(P(ax), P(ax), P(ax)), check_vma=False,
     ))
 
 

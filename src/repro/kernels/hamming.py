@@ -79,6 +79,9 @@ def hamming_count_kernel(q, r, *, d: int, bq: int = DEFAULT_BQ,
     (Q, nw) x (R, nw) -> (Q, 1) int32. The reference grid axis revisits the
     output block and accumulates (classic Pallas reduction pattern). d is a
     compile-time constant (the paper sweeps d in {0,1,2}).
+
+    Does not compile for TPU at the default blocks: the (bq, br, nw)
+    XOR block overflows the 16 MiB scoped VMEM (no search path calls it).
     """
     Q, nw = q.shape
     R = r.shape[0]

@@ -1,9 +1,15 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles padding to block multiples and platform dispatch: on TPU the
-compiled kernels run natively; elsewhere (this CPU container) they execute
-under ``interpret=True`` — same kernel body, Python evaluation — or fall
-back to the jnp reference for speed when ``prefer_ref=True``.
+compiled kernels run natively; elsewhere (the CPU) they execute under
+``interpret=True`` — same kernel body, Python evaluation — or fall back to
+the jnp reference for speed when ``prefer_ref=True``.
+
+The one shape-dependent route is candidate emission
+(:func:`emission_route`): the upper-mask kernel holds a (U+1, E)
+bucket-ownership compare and an (SB, E) one-hot in VMEM, so a slab past
+``EMIT_KERNEL_MAX_CELLS`` (the flip layout at d >= 1 on a few thousand
+sequences) takes the jnp product on every backend.
 """
 from __future__ import annotations
 
@@ -17,10 +23,14 @@ from ..core.alphabet import PAD
 from . import ref as kref
 from .hamming import hamming_count_kernel, hamming_dist_kernel
 from .siggen import siggen_accumulate_kernel
+from .spgemm import DEFAULT_SLOT_BLOCK
 from .sw import (on_tpu, resolve_interpret, sw_scores_kernel,
                  ungapped_scores_kernel, wave_scores_kernel)
 
-_on_tpu = on_tpu  # back-compat alias
+# Largest max(U+1, slot block) x E working set the emission kernel is
+# given. Compiled for v5e: 512 x 32768 compiles in ~20 s, 512 x 131072
+# runs out of VMEM, and the compile time grows with the product.
+EMIT_KERNEL_MAX_CELLS = 1 << 24
 
 
 def _pad_rows(x, mult, value=0):
@@ -39,7 +49,8 @@ def all_pairs_hamming(q, r, *, bq: int = 256, br: int = 256,
         return kref.hamming_dist_ref(q, r)
     qp, Q = _pad_rows(q, bq)
     rp, R = _pad_rows(r, br)
-    out = hamming_dist_kernel(qp, rp, bq=bq, br=br, interpret=not _on_tpu())
+    out = hamming_dist_kernel(qp, rp, bq=bq, br=br,
+                              interpret=resolve_interpret(None))
     return out[:Q, :R]
 
 
@@ -59,7 +70,7 @@ def hamming_counts(q, r, d: int, *, bq: int = 256, br: int = 256,
     PADV = jnp.uint32(0xFFFFFFFF)
     rp, R = _pad_rows(r, br, value=PADV)
     out = hamming_count_kernel(qp, rp, d=d, bq=bq, br=br,
-                               interpret=not _on_tpu())[:, 0]
+                               interpret=resolve_interpret(None))[:, 0]
     if rp.shape[0] != R:
         # exact correction: count hits of each query against the pad pattern
         pad_sig = jnp.full((1, r.shape[1]), PADV, jnp.uint32)
@@ -113,13 +124,14 @@ def wavefront_scores(qs, rs, *, gap_mode: str = "linear",
     return out[:B, 0]
 
 
-def ungapped_wave_scores(qs, rs, *, x: int = 20, bb: int = 8,
+def ungapped_wave_scores(qs, rs, *, x: int | None = 20, bb: int = 8,
                          prefer_ref: bool = False,
                          interpret: bool | None = None) -> jnp.ndarray:
     """Batched ungapped X-drop prefilter scores for a (B, Lq) x (B, Lr) pair
     block via the Pallas diagonal-scan kernel (padded + cropped); bit-exact
     with `align.smith_waterman.ungapped_xdrop_scores` (the ``prefer_ref``
-    fallback, which is also faster off-TPU)."""
+    fallback, which is also faster off-TPU). ``x=None`` drops the X-drop
+    test: the best ungapped segment."""
     if prefer_ref:
         from ..align.smith_waterman import ungapped_xdrop_scores
         return ungapped_xdrop_scores(qs, rs, x=x)
@@ -130,18 +142,29 @@ def ungapped_wave_scores(qs, rs, *, x: int = 20, bb: int = 8,
     return out[:B, 0]
 
 
+def emission_route(n_offsets: int, n_entries: int, cap: int) -> str:
+    """``"pallas"`` or ``"jnp"``: how :func:`emit_upper_pairs` emits a
+    band slab of ``n_offsets`` (U+1) offsets and ``n_entries`` ids on this
+    backend. The kernel runs natively on TPU within
+    ``EMIT_KERNEL_MAX_CELLS``; the jnp product covers everything else."""
+    sb = min(cap, DEFAULT_SLOT_BLOCK)
+    fits = max(n_offsets, sb) * n_entries <= EMIT_KERNEL_MAX_CELLS
+    return "pallas" if on_tpu() and fits else "jnp"
+
+
 def emit_upper_pairs(offs_s, ids_s, *, cap: int,
                      prefer_ref: bool | None = None,
                      interpret: bool | None = None) -> jnp.ndarray:
     """Band-stacked upper-mask SpGEMM candidate emission: offsets (G, U+1),
     ids (G, E) -> (G, cap, 2) int32 pair buffers (-1 past each band's true
     count) — the strict upper triangle of each band's AᵀA incidence
-    product. On TPU the Pallas kernel (`kernels/spgemm.py`) lowers
-    natively; elsewhere the jnp product of `repro.index.spgemm` is the
-    fast path (``prefer_ref`` default autodetects). Bit-exact across all
-    three paths (same pairs, same slot order)."""
+    product. ``prefer_ref=None`` takes :func:`emission_route`: the Pallas
+    kernel (`kernels/spgemm.py`) on TPU for slabs that fit it, the jnp
+    product of `repro.index.spgemm` otherwise. Bit-exact across all three
+    paths (same pairs, same slot order)."""
     if prefer_ref is None:
-        prefer_ref = not _on_tpu()
+        prefer_ref = emission_route(offs_s.shape[1], ids_s.shape[1],
+                                    cap) == "jnp"
     if prefer_ref:
         from ..index.spgemm import masked_pair_product
         return jax.vmap(
@@ -163,5 +186,5 @@ def signatures_fused(rows, cb, H, *, T: int, bs: int = 256, bw: int = 512,
     cbp, W = _pad_rows(cb, bw)
     Hp, _ = _pad_rows(H, bw)
     out = siggen_accumulate_kernel(rp, cbp, Hp, T=T, bs=bs, bw=bw,
-                                   interpret=not _on_tpu())
+                                   interpret=resolve_interpret(None))
     return out[:S]

@@ -64,6 +64,9 @@ def siggen_accumulate_kernel(rows, cb, H, *, T: int, bs: int = DEFAULT_BS,
       H:    (W, f) int8  — ±1 hyperplanes.
     Returns:
       V: (S, f) int32 (callers apply sign + pack_bits).
+
+    Does not compile for TPU: Mosaic has no int32 matmul (no search path
+    calls it; signatures use the ``table`` method of `core.simhash`).
     """
     S, D = rows.shape
     W, f = H.shape

@@ -1,28 +1,34 @@
-"""Pallas TPU kernels: batched Smith-Waterman row-wave DP and the ungapped
-X-drop prefilter over a pair block.
+"""Pallas TPU kernels: batched Smith-Waterman over a pair block — the
+anti-diagonal (wavefront) Gotoh sweep, the ungapped X-drop prefilter, and
+the legacy row wave.
 
 The all-pairs tiler's inner loop (`repro.allpairs.tiles`): score a block of
-(query, reference) pairs in one program. The grid is 1-D over pair blocks;
-each program holds a (bb, Lq) query block and a (bb, Lr) reference block in
-VMEM and scans query rows with `fori_loop`, keeping only the previous DP row
-(bb, Lr+1) and the running best — O(bb*Lr) state, never the full matrix.
+(query, reference) pairs in one program. The wavefront and ungapped
+kernels share one body over the skewed substitution block of
+`align.gotoh` (``sk[c, b, i] = s_b[i, c-i]``): lanes are query rows, so
+every DP cell's predecessors sit on the two previous anti-diagonals and
+one diagonal step is elementwise arithmetic over (bb, Lq) lanes — the
+gapped step is the wavefront recurrence, the ungapped step keeps only the
+diagonal move with BLAST's X-drop restart. The grid is (pair block,
+diagonal block); the DP carries live in VMEM scratch across the diagonal
+axis, so VMEM holds one (dc, bb, Lq) int8 slice of the skewed block and
+the carries, whatever the pair length.
+
+Everything is written in the subset Mosaic lowers: the per-diagonal
+substitution row is a dynamic index on the ref's leading axis
+(``sk_ref[c]``), and the one-lane shift is a lane rotate plus a mask
+(``pltpu.roll``) — neither a ``dynamic_slice`` of a loaded value nor an
+unaligned lane concatenate.
 
 ``interpret`` defaults to *autodetect*: kernels lower natively wherever the
 backend supports Pallas TPU lowering and fall back to interpret mode only
-where it is unavailable (this CPU container). Pass ``interpret=True/False``
-to override (exposed as ``WaveConfig.pallas_interpret``).
+where it is unavailable (the CPU). Pass ``interpret=True/False`` to
+override (exposed as ``WaveConfig.pallas_interpret``).
 
-Per row the within-row gap dependency is resolved by the same max-plus
-prefix scan as :mod:`repro.align.smith_waterman` (H = cummax(A + c*t) - c*t),
-implemented lane-parallel with a log-doubling shifted-max (Hillis-Steele),
-since `lax.cummax` does not lower inside Pallas TPU kernels. Substitution
-scores are looked up without gathers: the per-row BLOSUM slice B[q_i] is
-prefetched as a (bb, Lq, A+1) tensor and reduced against one-hot reference
-comparisons — 21 vectorized selects per row, MXU/VPU-friendly.
-
-Cell values are integer and identical to the classic recurrence: scores are
-bit-exact with `align.smith_waterman.sw_align_batch` (the jnp wave) and with
-the per-pair path.
+Cell values are integer: scores are bit-exact with the jnp wavefront sweep
+(`align.gotoh`), the jnp ungapped scan
+(`align.smith_waterman.ungapped_xdrop_scores`) and the row wave
+(`align.smith_waterman.sw_align_batch`).
 """
 from __future__ import annotations
 
@@ -31,11 +37,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..align.gotoh import _BSENT, GAP_EXTEND, GAP_OPEN, SENT8
 from ..align.smith_waterman import GAP, NEG
 from ..core.alphabet import ALPHABET_SIZE, BLOSUM62_PADDED, PAD
 
 DEFAULT_BB = 8
+DIAG_BLOCK = 128        # anti-diagonals per grid step of the wave kernels
 
 
 def on_tpu() -> bool:
@@ -49,29 +58,176 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return (not on_tpu()) if interpret is None else bool(interpret)
 
 
-def _sw_kernel(q_ref, qsub_ref, r_ref, out_ref, *, Lq: int):
-    q = q_ref[...].astype(jnp.int32)          # (bb, Lq)
-    qsub = qsub_ref[...]                      # (bb, Lq, A+1) int32
+def _shift_right(v, lane0):
+    """``v`` moved one lane up the query axis (lane i reads lane i-1),
+    zero-filled at lane 0."""
+    return jnp.where(lane0, 0, pltpu.roll(v, 1, 1))
+
+
+def _wave_kernel(sk_ref, out_ref, *carry, mode: str, gap_open: int,
+                 gap_extend: int, x: int | None, dc: int):
+    """``dc`` diagonals of one (bb,) pair block. ``carry[0]`` is the
+    running best; the rest are the mode's DP lanes, all zero at the first
+    diagonal block and written back to VMEM scratch after each one."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        for ref in carry:
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, carry[0].shape, 1) == 0
+
+    def shift(v):
+        return _shift_right(v, lane0)
+
+    def step(c, st):
+        s = sk_ref[c].astype(jnp.int32)       # (bb, Lq) diagonal c
+        if mode == "linear":
+            best, h1, h2s = st
+            h1s = shift(h1)
+            h = jnp.maximum(jnp.maximum(h2s + s, 0),
+                            jnp.maximum(h1, h1s) + gap_open)
+            return jnp.maximum(best, h), h, h1s
+        if mode == "affine":
+            best, h1, h2s, e1, f1 = st
+            h1s = shift(h1)
+            e = jnp.maximum(e1 + gap_extend, h1 + gap_open)
+            f = jnp.maximum(shift(f1) + gap_extend, h1s + gap_open)
+            h = jnp.maximum(jnp.maximum(h2s + s, 0), jnp.maximum(e, f))
+            return jnp.maximum(best, h), h, h1s, e, f
+        # ungapped: the run of (i-1, j-1) extends by s; a sentinel cell
+        # (PAD or outside the matrix) restarts it like the masked jnp scan
+        best, a1, a2s, *rb = st
+        v = a2s + s
+        drop = (v <= 0) | (s == SENT8)
+        if x is None:
+            v = jnp.where(drop, 0, v)
+            return jnp.maximum(best, v), v, shift(a1)
+        r1, r2s = rb
+        drop = drop | (r2s - v > x)
+        v = jnp.where(drop, 0, v)
+        r = jnp.where(drop, 0, jnp.maximum(r2s, v))
+        return jnp.maximum(best, v), v, shift(a1), r, shift(r1)
+
+    st = jax.lax.fori_loop(0, dc, step, tuple(ref[...] for ref in carry))
+    for ref, v in zip(carry, st):
+        ref[...] = v
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _emit():
+        out_ref[...] = jnp.max(st[0], axis=1, keepdims=True)
+
+
+def _wave_call(sk, *, mode: str, bb: int, interpret: bool | None,
+               gap_open: int = 0, gap_extend: int = 0,
+               x: int | None = None):
+    """Run the shared wave kernel over a skewed (nd, B, Lq) int8 block ->
+    (B, 1) int32. The diagonal axis pads to a ``dc`` multiple with
+    sentinel rows, which no score can come from (`align.gotoh`)."""
+    nd, B, Lq = sk.shape
+    assert B % bb == 0, "pad the pair block to a bb multiple"
+    if x is not None:       # past 11 * L no run can drop; keep it int32
+        x = min(int(x), 1 << 30)
+    dc = min(DIAG_BLOCK, nd)
+    pad = (-nd) % dc
+    if pad:
+        sk = jnp.concatenate(
+            [sk, jnp.full((pad, B, Lq), SENT8, jnp.int8)], axis=0)
+    n_carry = 5 if mode == "affine" or (mode == "ungapped"
+                                        and x is not None) else 3
+    return pl.pallas_call(
+        functools.partial(_wave_kernel, mode=mode, gap_open=gap_open,
+                          gap_extend=gap_extend, x=x, dc=dc),
+        grid=(B // bb, (nd + pad) // dc),
+        in_specs=[pl.BlockSpec((dc, bb, Lq), lambda i, k: (k, i, 0))],
+        out_specs=pl.BlockSpec((bb, 1), lambda i, k: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bb, Lq), jnp.int32)] * n_carry,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(sk)
+
+
+def _skewed(qs, rs):
+    """(B, Lq) x (B, Lr) int8 -> the (nd, B, Lq) int8 skewed substitution
+    block the wave kernels sweep, ``sk[c, b, i] = s_b[i, c-i]`` (SENT8 on
+    PAD and outside the matrix). The reference is skewed by one gather of
+    column ``c - i`` and the query axis resolved by 20 selects. This is
+    bit-identical to `align.gotoh`'s pad-reshape skew, which the TPU
+    compiler takes ~25 s per shape to lay out at L=640 (this form: ~2 s)."""
+    B, Lq = qs.shape
+    Lr = rs.shape[1]
+    nd = Lq + Lr - 1
+    c = jax.lax.broadcasted_iota(jnp.int32, (nd, Lq), 0)
+    j = c - jax.lax.broadcasted_iota(jnp.int32, (nd, Lq), 1)
+    j = jnp.where((j >= 0) & (j < Lr), j, Lr)         # Lr -> the PAD column
+    rp = jnp.concatenate([rs, jnp.full((B, 1), PAD, rs.dtype)], axis=1)
+    rsk = jnp.transpose(jnp.take(rp, j, axis=1), (1, 0, 2))  # (nd, B, Lq)
+    table = jnp.asarray(_BSENT)
+    q = qs.astype(jnp.int32)
+    out = jnp.full(rsk.shape, SENT8, jnp.int8)
+    for a in range(ALPHABET_SIZE):
+        out = jnp.where(rsk == a, table[a][q][None], out)
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "gap_mode", "gap_open", "gap_extend", "bb", "interpret"))
+def wave_scores_kernel(qs, rs, *, gap_mode: str = "linear",
+                       gap_open: int | None = None,
+                       gap_extend: int | None = None,
+                       bb: int = DEFAULT_BB,
+                       interpret: bool | None = None):
+    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores
+    via the wavefront kernel. ``gap_mode="linear"`` (default gap = GAP) is
+    bit-exact with `sw_scores_kernel`; ``"affine"`` scores Gotoh gaps
+    (defaults -11/-1), bit-exact with `kernels.ref.sw_affine_ref`.
+
+    B % bb == 0 is handled by padding in ops.wavefront_scores.
+    """
+    if gap_mode == "affine":
+        go = GAP_OPEN if gap_open is None else int(gap_open)
+        ge = GAP_EXTEND if gap_extend is None else int(gap_extend)
+    else:
+        go = GAP if gap_open is None else int(gap_open)
+        ge = go
+    return _wave_call(_skewed(qs, rs), mode=gap_mode, bb=bb,
+                      interpret=interpret, gap_open=go, gap_extend=ge)
+
+
+@functools.partial(jax.jit, static_argnames=("x", "bb", "interpret"))
+def ungapped_scores_kernel(qs, rs, *, x: int | None, bb: int = DEFAULT_BB,
+                           interpret: bool | None = None):
+    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best ungapped
+    X-drop run scores (``x=None``: no drop test, the best ungapped
+    segment); bit-exact with `align.smith_waterman.ungapped_xdrop_scores`."""
+    return _wave_call(_skewed(qs, rs), mode="ungapped", bb=bb,
+                      interpret=interpret, x=x)
+
+
+def _sw_kernel(qsub_ref, r_ref, out_ref, *, Lq: int):
+    """Row wave: scan query rows with `fori_loop`, keeping only the
+    previous DP row (bb, Lr+1) and the running best. Row i's substitution
+    slice arrives PAD-masked as ``qsub_ref[i]`` (bb, A+1), so the row
+    needs neither the query residue nor a dynamic slice; the within-row
+    gap dependency is the max-plus prefix scan H = cummax(A + c*t) - c*t,
+    as a log-doubling shifted max."""
     r = r_ref[...].astype(jnp.int32)          # (bb, Lr)
     bb, Lr = r.shape
     c = jnp.int32(-GAP)
     # iota, not arange: pallas kernels may not capture constant arrays
     t = jax.lax.broadcasted_iota(jnp.int32, (1, Lr), 1) + 1  # (1, Lr)
-    r_pad = r == PAD
 
     def row_step(i, carry):
         prev, best = carry                    # (bb, Lr+1), (bb, 1)
-        qi = jax.lax.dynamic_index_in_dim(q, i, axis=1, keepdims=False)
-        si = jax.lax.dynamic_index_in_dim(qsub, i, axis=1, keepdims=False)
+        si = qsub_ref[i]                      # (bb, A+1) int32
         # sub_row[b, j] = B[q[b, i], r[b, j]] via 21 selects (no gathers)
         sub_row = jnp.zeros((bb, Lr), jnp.int32)
         for a in range(ALPHABET_SIZE + 1):
-            sub_row = jnp.where(r == a, si[:, a][:, None], sub_row)
-        masked = r_pad | (qi == PAD)[:, None]
-        sub_row = jnp.where(masked, NEG, sub_row)
+            sub_row = jnp.where(r == a, si[:, a:a + 1], sub_row)
         a_row = jnp.maximum(0, jnp.maximum(prev[:, :-1] + sub_row,
                                            prev[:, 1:] + GAP))
-        # lane-parallel prefix max of (a_row + c*t): log-doubling shifts
         x = a_row + c * t
         s = 1
         while s < Lr:
@@ -94,164 +250,26 @@ def _sw_kernel(q_ref, qsub_ref, r_ref, out_ref, *, Lq: int):
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def sw_scores_kernel(qs, rs, *, bb: int = DEFAULT_BB,
                      interpret: bool | None = None):
-    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores.
-
-    B % bb == 0 is handled by padding in ops.sw_wave_scores.
-    ``interpret=None`` autodetects (native lowering on TPU).
-    """
+    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores
+    via the row wave. B % bb == 0 is handled by padding in
+    ops.sw_wave_scores. ``interpret=None`` autodetects (native lowering on
+    TPU)."""
     B, Lq = qs.shape
     Lr = rs.shape[1]
     assert B % bb == 0, "pad the pair block to a bb multiple"
-    qsub = jnp.asarray(BLOSUM62_PADDED)[qs.astype(jnp.int32)]  # (B, Lq, A+1)
-    grid = (B // bb,)
+    q = qs.astype(jnp.int32)
+    masked = (q == PAD)[..., None] | (
+        jnp.arange(ALPHABET_SIZE + 1) == PAD)
+    qsub = jnp.where(masked, NEG, jnp.asarray(BLOSUM62_PADDED)[q])
+    qsub = jnp.transpose(qsub, (1, 0, 2))     # (Lq, B, A+1) int32
     return pl.pallas_call(
         functools.partial(_sw_kernel, Lq=Lq),
-        grid=grid,
+        grid=(B // bb,),
         in_specs=[
-            pl.BlockSpec((bb, Lq), lambda i: (i, 0)),
-            pl.BlockSpec((bb, Lq, ALPHABET_SIZE + 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((Lq, bb, ALPHABET_SIZE + 1), lambda i: (0, i, 0)),
             pl.BlockSpec((bb, Lr), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=resolve_interpret(interpret),
-    )(qs, qsub, rs)
-
-
-def _wave_sw_kernel(sk_ref, out_ref, *, gap_open: int, gap_extend: int,
-                    affine: bool):
-    """Anti-diagonal (wavefront) SW sweep over a (bb,) pair block. The
-    skewed substitution block sk[c, b, i] = s_b[i, c-i] arrives
-    precomputed and sentinel-padded (`align.gotoh`), so each diagonal
-    step is pure elementwise arithmetic over (bb, Lq) lanes — no prefix
-    scan, no gathers, no masking pass. ``affine`` threads the Gotoh E/F
-    gap lanes; with it off the step is the linear 3-way max."""
-    sk = sk_ref[...].astype(jnp.int32)        # (nd, bb, Lq)
-    nd, bb, Lq = sk.shape
-    z = jnp.zeros((bb, Lq), jnp.int32)
-
-    def shift(x):
-        return jnp.concatenate(
-            [jnp.zeros((bb, 1), jnp.int32), x[:, :-1]], axis=1)
-
-    if affine:
-        def step(c, carry):
-            h1, h2s, e1, f1, best = carry
-            s = jax.lax.dynamic_index_in_dim(sk, c, axis=0, keepdims=False)
-            h1s = shift(h1)
-            e = jnp.maximum(e1 + gap_extend, h1 + gap_open)
-            f = jnp.maximum(shift(f1) + gap_extend, h1s + gap_open)
-            h = jnp.maximum(jnp.maximum(h2s + s, 0), jnp.maximum(e, f))
-            return h, h1s, e, f, jnp.maximum(best, h)
-
-        init = (z, z, z, z, z)
-    else:
-        def step(c, carry):
-            h1, h2s, best = carry
-            s = jax.lax.dynamic_index_in_dim(sk, c, axis=0, keepdims=False)
-            h1s = shift(h1)
-            h = jnp.maximum(jnp.maximum(h2s + s, 0),
-                            jnp.maximum(h1, h1s) + gap_open)
-            return h, h1s, jnp.maximum(best, h)
-
-        init = (z, z, z)
-
-    out = jax.lax.fori_loop(0, nd, step, init)
-    out_ref[...] = jnp.max(out[-1], axis=1, keepdims=True)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "gap_mode", "gap_open", "gap_extend", "bb", "interpret"))
-def wave_scores_kernel(qs, rs, *, gap_mode: str = "linear",
-                       gap_open: int | None = None,
-                       gap_extend: int | None = None,
-                       bb: int = DEFAULT_BB,
-                       interpret: bool | None = None):
-    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores
-    via the wavefront kernel. ``gap_mode="linear"`` (default gap = GAP) is
-    bit-exact with `sw_scores_kernel`; ``"affine"`` scores Gotoh gaps
-    (defaults -11/-1), bit-exact with `kernels.ref.sw_affine_ref`.
-
-    B % bb == 0 is handled by padding in ops.wavefront_scores.
-    """
-    from ..align.gotoh import GAP_EXTEND, GAP_OPEN, _skew_flat, _sub_block
-    B, Lq = qs.shape
-    assert B % bb == 0, "pad the pair block to a bb multiple"
-    if gap_mode == "affine":
-        go = GAP_OPEN if gap_open is None else int(gap_open)
-        ge = GAP_EXTEND if gap_extend is None else int(gap_extend)
-    else:
-        go = GAP if gap_open is None else int(gap_open)
-        ge = go
-    sk = jnp.transpose(_skew_flat(_sub_block(qs, rs)), (0, 2, 1))
-    nd = sk.shape[0]                          # (nd, B, Lq) int8
-    return pl.pallas_call(
-        functools.partial(_wave_sw_kernel, gap_open=go, gap_extend=ge,
-                          affine=(gap_mode == "affine")),
-        grid=(B // bb,),
-        in_specs=[pl.BlockSpec((nd, bb, Lq), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(sk)
-
-
-def _ungapped_kernel(q_ref, qsub_ref, r_ref, out_ref, *, Lq: int, x: int):
-    """Ungapped X-drop diagonal scan over a (bb,) pair block — the prefilter
-    twin of `_sw_kernel`. Carries are indexed by reference column, so the
-    diagonal predecessor is a right-shift: every row is elementwise (no
-    prefix scan), O(bb*Lr) state."""
-    q = q_ref[...].astype(jnp.int32)          # (bb, Lq)
-    qsub = qsub_ref[...]                      # (bb, Lq, A+1) int32
-    r = r_ref[...].astype(jnp.int32)          # (bb, Lr)
-    bb, Lr = r.shape
-    r_pad = r == PAD
-
-    def row_step(i, carry):
-        cur, rbest, gbest = carry             # (bb, Lr) x2, (bb, 1)
-        qi = jax.lax.dynamic_index_in_dim(q, i, axis=1, keepdims=False)
-        si = jax.lax.dynamic_index_in_dim(qsub, i, axis=1, keepdims=False)
-        sub_row = jnp.zeros((bb, Lr), jnp.int32)
-        for a in range(ALPHABET_SIZE + 1):
-            sub_row = jnp.where(r == a, si[:, a][:, None], sub_row)
-        masked = r_pad | (qi == PAD)[:, None]
-        sub_row = jnp.where(masked, NEG, sub_row)
-        cur_s = jnp.concatenate(
-            [jnp.zeros((bb, 1), jnp.int32), cur[:, :-1]], axis=1)
-        rb_s = jnp.concatenate(
-            [jnp.zeros((bb, 1), jnp.int32), rbest[:, :-1]], axis=1)
-        c = cur_s + sub_row
-        drop = (c <= 0) | (rb_s - c > x)
-        c = jnp.where(drop, 0, c)
-        rb = jnp.where(drop, 0, jnp.maximum(rb_s, c))
-        gbest = jnp.maximum(gbest, jnp.max(c, axis=1, keepdims=True))
-        return c, rb, gbest
-
-    z = jnp.zeros((bb, Lr), jnp.int32)
-    _, _, best = jax.lax.fori_loop(
-        0, Lq, row_step, (z, z, jnp.zeros((bb, 1), jnp.int32)))
-    out_ref[...] = best
-
-
-@functools.partial(jax.jit, static_argnames=("x", "bb", "interpret"))
-def ungapped_scores_kernel(qs, rs, *, x: int, bb: int = DEFAULT_BB,
-                           interpret: bool | None = None):
-    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best ungapped
-    X-drop run scores; bit-exact with
-    `align.smith_waterman.ungapped_xdrop_scores`."""
-    B, Lq = qs.shape
-    Lr = rs.shape[1]
-    assert B % bb == 0, "pad the pair block to a bb multiple"
-    qsub = jnp.asarray(BLOSUM62_PADDED)[qs.astype(jnp.int32)]  # (B, Lq, A+1)
-    return pl.pallas_call(
-        functools.partial(_ungapped_kernel, Lq=Lq, x=x),
-        grid=(B // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, Lq), lambda i: (i, 0)),
-            pl.BlockSpec((bb, Lq, ALPHABET_SIZE + 1), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, Lr), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(qs, qsub, rs)
+    )(qsub, rs)

@@ -152,14 +152,16 @@ def main(argv=None):
     from ..index import SignatureIndex, occupancy_report
 
     import jax
+
     if args.shards > 1 and jax.device_count() < args.shards:
         # no silent fallback: the self-join would run its one-device vmap
         # path and waves would clamp to one device
         raise SystemExit(
-            f"--shards {args.shards} needs that many devices, have "
-            f"{jax.device_count()} (XLA_FLAGS was already set in the "
-            f"environment? add --xla_force_host_platform_device_count="
-            f"{args.shards} to it)")
+            f"--shards {args.shards} needs that many devices; this "
+            f"process sees {jax.device_count()} "
+            f"{jax.devices()[0].platform} device(s) (on a CPU host, "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{args.shards} provides them)")
 
     corpus = make_family_corpus(FamilyCorpusConfig(
         n_families=args.n_families, family_size=args.family_size,
@@ -307,4 +309,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from ..util import use_compile_cache
+    use_compile_cache()
     main()

@@ -334,8 +334,11 @@ def main(argv=None):
         from jax.sharding import Mesh
         if jax.device_count() < args.shards:
             raise SystemExit(
-                f"--shards {args.shards} needs that many devices, have "
-                f"{jax.device_count()} (XLA_FLAGS was already set?)")
+                f"--shards {args.shards} needs that many devices; this "
+                f"process sees {jax.device_count()} "
+                f"{jax.devices()[0].platform} device(s) (on a CPU host, "
+                f"XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{args.shards} provides them)")
         # mesh sized by --shards (== the index's persisted n_shards), not
         # by whatever the process happens to expose
         mesh = Mesh(np.array(jax.devices()[:args.shards]), ("data",))
@@ -441,4 +444,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from ..util import use_compile_cache
+    use_compile_cache()
     main()

@@ -219,7 +219,6 @@ def seq_sharded_decode_attention(q, cache, k_new, v_new, positions, cfg,
     positions: (1,) absolute. Returns (out (B,1,H,Dh), new_cache).
     """
     from jax.sharding import PartitionSpec as P
-    from ..util import shard_map_compat
 
     B, S, H, Dh = q.shape
     Kh = k_new.shape[2]
@@ -265,12 +264,12 @@ def seq_sharded_decode_attention(q, cache, k_new, v_new, positions, cfg,
         out = (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(qL.dtype)
         return out.reshape(Bl, S, H, Dh), kC, vC, pC
 
-    fn = shard_map_compat(
-        local_fn, mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(P(bspec), P(bspec, "model"), P(bspec, "model"),
                   P("model"), P(bspec), P(bspec), P()),
         out_specs=(P(bspec), P(bspec, "model"), P(bspec, "model"),
-                   P("model")))
+                   P("model")), check_vma=False)
     out, ck, cv, cpos = fn(q, cache["k"], cache["v"], cache["pos"],
                            k_new, v_new, positions)
     return out, {"k": ck, "v": cv, "pos": cpos}

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..util import shard_map_compat
 
 BLOCK = 2048
 
@@ -100,10 +99,10 @@ def make_compressed_dp_step(loss_fn, mesh, axis_name: str = "data",
 
     def step(state, batch):
         params, residual = state      # residual: (n_shards, nvec)
-        fn = shard_map_compat(
-            local_step, mesh,
+        fn = jax.shard_map(
+            local_step, mesh=mesh,
             in_specs=(P(), P(axis_name), P(axis_name)),
-            out_specs=(P(), P(axis_name), P()))
+            out_specs=(P(), P(axis_name), P()), check_vma=False)
         new_params, new_res, loss = fn(params, residual, batch)
         return (new_params, new_res), loss
 

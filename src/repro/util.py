@@ -1,7 +1,8 @@
-"""Small compat utilities."""
+"""Small shared utilities."""
 from __future__ import annotations
 
-import jax
+import os
+from pathlib import Path
 
 
 def next_pow2(x: int) -> int:
@@ -11,17 +12,18 @@ def next_pow2(x: int) -> int:
     return 1 << (int(x) - 1).bit_length() if x > 0 else 0
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_rep -> check_vma rename)."""
-    try:
-        from jax import shard_map as sm
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    the cache and nothing is set here; otherwise the cache lives at the
+    fixed ``.jax_cache/`` of the checkout — a stable path, so a later run
+    finds what an earlier one compiled. Called by the launchers and
+    ``chip_smoke.py``; tests leave the cache off."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    # the checkout root: this file is src/repro/util.py
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

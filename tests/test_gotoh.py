@@ -158,13 +158,17 @@ def test_score_pairs_validates_knobs(block):
 
 
 # ------------------------------------------------------- Pallas kernel
+@pytest.mark.parametrize("B,Lq,Lr", [
+    (11, 40, 36),       # one diagonal block
+    (10, 96, 80),       # 175 diagonals: the carries cross a block boundary
+])
 @pytest.mark.parametrize("gap_mode", ["linear", "affine"])
-def test_pallas_wavefront_kernel_parity(gap_mode):
+def test_pallas_wavefront_kernel_parity(gap_mode, B, Lq, Lr):
     """The Pallas wavefront kernel (interpret mode off-TPU) is bit-exact
     with the jnp sweep, including a non-multiple-of-bb batch with an
     all-PAD row."""
     rng = np.random.default_rng(3)
-    qs, rs = _ragged_block(rng, 11, 40, 36, all_pad_rows=(4,),
+    qs, rs = _ragged_block(rng, B, Lq, Lr, all_pad_rows=(4,),
                            len1_rows=(6,))
     got = np.asarray(ops.wavefront_scores(qs, rs, gap_mode=gap_mode))
     want = np.asarray(ops.wavefront_scores(qs, rs, gap_mode=gap_mode,

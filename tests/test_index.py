@@ -298,7 +298,7 @@ np.testing.assert_array_equal(nid, np.asarray(want_id))
 np.testing.assert_array_equal(nd, np.asarray(want_d))
 print("OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=

@@ -76,6 +76,8 @@ def test_interpret_autodetect_off_tpu():
     (4, 24, 24, 20),       # square block, finite X
     (5, 17, 33, 20),       # ragged -> bb padding
     (8, 16, 16, 2**30),    # x -> inf (plain best ungapped segment)
+    (6, 90, 70, 20),       # 159 diagonals: carries cross a block boundary
+    (7, 40, 30, None),     # no X-drop test at all (the prefilter's x=None)
 ])
 def test_ungapped_kernel_matches_jnp(B, Lq, Lr, x):
     from repro.align.smith_waterman import ungapped_xdrop_scores
@@ -89,7 +91,7 @@ def test_ungapped_kernel_matches_jnp(B, Lq, Lr, x):
         rs[n, rng.integers(Lr // 2, Lr):] = PAD
     got = np.asarray(ops.ungapped_wave_scores(qs, rs, x=x, bb=4))
     want = np.asarray(ungapped_xdrop_scores(
-        qs, rs, x=None if x >= 2**30 else x))
+        qs, rs, x=None if x is None or x >= 2**30 else x))
     np.testing.assert_array_equal(got, want)
 
 

@@ -581,7 +581,7 @@ def test_lifecycle_grid_forced_four_devices():
     """(n_segments, n_shards) acceptance grid under XLA-forced 4 host
     devices: the real ppermute ring probes base+delta slabs bit-exact
     with the from-scratch rebuild, before and after compaction."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", _SUBPROCESS], env=env,

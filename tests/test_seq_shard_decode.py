@@ -67,6 +67,7 @@ _CHECK = textwrap.dedent("""
 def test_seq_sharded_decode_matches_unsharded():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"     # forced host devices: a CPU run
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, "-c", _CHECK],

@@ -343,7 +343,7 @@ print("WAVES-EXACT")
 def test_sharded_paths_forced_four_devices():
     """The real multi-device programs (shard_map emission, ppermute probe
     ring, SPMD-split waves) under XLA_FLAGS-forced 4 host devices."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", _SUBPROCESS], env=env,

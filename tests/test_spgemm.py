@@ -232,6 +232,21 @@ def test_upper_kernel_matches_ref_and_product():
             np.testing.assert_array_equal(prod[b], ref)
 
 
+@pytest.mark.parametrize("tpu,U1,E,cap,route", [
+    (True, 1662, 4146, 8192, "pallas"),       # flip d=0, 4,146-seq corpus
+    (True, 22, 1331, 1 << 17, "pallas"),      # one shard of a 4-shard join
+    (True, 37022, 136818, 1 << 20, "jnp"),    # flip d=1: past the budget
+    (True, 16, 1 << 16, 1 << 16, "jnp"),      # the (SB, E) one-hot alone
+    (False, 1662, 4146, 8192, "jnp"),         # off TPU: the jnp product
+])
+def test_emission_route_budget(monkeypatch, tpu, U1, E, cap, route):
+    """Emission takes the Pallas kernel only on TPU and only for slabs
+    whose quadratic working set fits ``EMIT_KERNEL_MAX_CELLS``."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    assert ops.emission_route(U1, E, cap) == route
+
+
 # ------------------------------------------------------- recompile sentinel
 def test_spgemm_steady_state_no_recompiles(index):
     """Warmed joins retrace nothing: the fused keyed program, the dedup

@@ -1,0 +1,101 @@
+"""Compile the main-path Pallas kernels for a v5e chip, without the chip.
+
+The TPU compiler is installed with jax, so each kernel is lowered and
+compiled here for a described (not attached) v5e at the widths the main
+path sends it: Swiss-Prot-shaped DP waves padded to ``len_quantum=64``,
+the longest wave the ``max_wave_cells`` plan sends, the all-pairs corpus's
+emission slabs, and a dense top-k sweep over a Swiss-Prot-sized index.
+Interpret-mode tests cannot see what this catches: a dynamic slice Mosaic
+cannot lower, a block that breaks the (8, 128) tiling rule, a kernel past
+the scoped VMEM limit. Nothing runs, so results are the other tests' job.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.allpairs import WaveConfig
+from repro.kernels.hamming import hamming_dist_kernel
+from repro.kernels.spgemm import upper_pairs_kernel
+from repro.kernels.sw import ungapped_scores_kernel, wave_scores_kernel
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _wave_batch(batch: int, L: int) -> int:
+    """The pair batch ``allpairs.tiles`` sends at (L, L) under the default
+    cell budget, padded to the kernel's 8-pair block."""
+    b = max(1, min(batch, WaveConfig().max_wave_cells // (L * L)))
+    return -(-b // 8) * 8
+
+
+@pytest.mark.parametrize("gap_mode", ["linear", "affine"])
+@pytest.mark.parametrize("L", [384, 1024])
+def test_wave_scores_kernel_compiles(one_chip, gap_mode, L):
+    B = _wave_batch(WaveConfig().wave_batch, L)
+    text = _compiled_text(
+        lambda q, r: wave_scores_kernel(q, r, gap_mode=gap_mode,
+                                        interpret=False),
+        one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("L,x", [(128, None), (384, None), (384, 20),
+                                 (1024, None)])
+def test_ungapped_scores_kernel_compiles(one_chip, L, x):
+    B = _wave_batch(WaveConfig().prefilter_batch, L)
+    text = _compiled_text(
+        lambda q, r: ungapped_scores_kernel(q, r, x=x, interpret=False),
+        one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
+    assert "tpu_custom_call" in text
+
+
+# (G bands, U+1 offsets, E entries, cap) of the NC_000913-shaped corpus
+# (4,146 sequences, k=3 T=13 f=32): the one-shard d=0 flip slab, and one
+# device's slab of the 4-shard d=1 band self-join
+@pytest.mark.parametrize("G,U1,E,cap", [(1, 1662, 4146, 8192),
+                                        (2, 22, 1331, 131072)])
+def test_upper_pairs_kernel_compiles(one_chip, G, U1, E, cap):
+    text = _compiled_text(
+        lambda o, i: upper_pairs_kernel(o, i, cap=cap, interpret=False),
+        one_chip, ((G, U1), jnp.int32), ((G, E), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_hamming_dist_kernel_compiles(one_chip):
+    # one 64-query serving batch (padded to the 256-row block) against a
+    # 454,401-reference index (padded to 454,656), f=32
+    text = _compiled_text(
+        lambda q, r: hamming_dist_kernel(q, r, interpret=False),
+        one_chip, ((256, 1), jnp.uint32), ((454656, 1), jnp.uint32))
+    assert "tpu_custom_call" in text

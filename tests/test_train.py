@@ -204,6 +204,7 @@ _COMPRESSED_DP = textwrap.dedent("""
 def test_compressed_dp_convergence_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"     # forced host devices: a CPU run
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, "-c", _COMPRESSED_DP],
