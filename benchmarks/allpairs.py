@@ -30,7 +30,8 @@ CSV: bench,n_seqs,method,metric,value.  ``--json`` (implied by ``--smoke``)
 additionally writes BENCH_allpairs.json — pairs/sec, waves, prefilter
 reject rate, wall-clock — which the nightly CI job uploads so the perf
 trajectory is tracked across PRs.  ``--profile`` reports the host-gather
-vs device-DP time split of both pipelines, making the win attributable.
+vs device-DP time split of both pipelines from their ``host_gather``,
+``wave`` and ``drain`` spans, making the win attributable.
 """
 from __future__ import annotations
 
@@ -179,6 +180,30 @@ def emission_sweep(csv=print, *, n: int, reps: int = 10,
     return out
 
 
+def _span_split(ids, lens, pairs, wc):
+    """Seconds of ``score_pairs``' host-gather, wave (by kind) and drain
+    spans, sorted by name. With ``wc.profile`` each wave blocks inside its
+    span, so the wave spans hold the device time."""
+    from repro.obs import TRACER
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        score_pairs(ids, lens, pairs, wc)
+    finally:
+        TRACER.disable()
+    split: dict[str, float] = {}
+    for sp in TRACER.spans():
+        if sp["name"] == "wave":
+            key = f"wave_{sp['args']['kind']}"
+        elif sp["name"] in ("host_gather", "drain"):
+            key = sp["name"]
+        else:
+            continue
+        split[key] = split.get(key, 0.0) + sp["dur"]
+    TRACER.clear()
+    return sorted(split.items())
+
+
 def run(csv=print, n_seqs: int = 2048, naive_sample: int = 192,
         use_pallas: bool = False, profile: bool = False,
         json_path: str | None = None, dp_kernel: str = "all",
@@ -299,9 +324,8 @@ def run(csv=print, n_seqs: int = 2048, naive_sample: int = 192,
     # ---- attribution: host-gather vs device-DP split (--profile) ---------
     if profile:
         for name, wc in (("pr2", pr2), ("device", devw)):
-            sp = score_pairs(ids, lens, join.pairs,
-                             dataclasses.replace(wc, profile=True))
-            for k, v in sp.timings.items():
+            for k, v in _span_split(ids, lens, join.pairs,
+                                    dataclasses.replace(wc, profile=True)):
                 csv(f"allpairs,{n},{name},profile_{k}_s,{v:.3f}")
 
     if json_path:

@@ -38,6 +38,7 @@ import numpy as np
 
 from ..core.pipeline import LSHConfig
 from ..index.store import SignatureIndex
+from ..obs import span
 from .graph import (FamilyForest, FamilyResult, ForestMismatch,
                     cluster_families, families_from_labels, threshold_edges,
                     union_find)
@@ -121,13 +122,14 @@ def all_pairs_search(ids, lens, cfg: AllPairsConfig | None = None,
                          max_pairs=cfg.max_pairs, n_shards=cfg.n_shards,
                          prefilter=pf, join_impl=cfg.join_impl)
     scored = score_pairs(ids, lens, join.pairs, wave)
-    if cfg.wave.with_pid:
-        families = cluster_families(index.size, join.pairs, scored.pid,
-                                    min_pid=cfg.min_pid)
-    else:       # score-only waves (e.g. the Pallas kernel path)
-        families = cluster_families(index.size, join.pairs, None,
-                                    scores=scored.scores,
-                                    min_score=cfg.min_score)
+    with span("graph", cat="allpairs", pairs=len(join.pairs)):
+        if cfg.wave.with_pid:
+            families = cluster_families(index.size, join.pairs, scored.pid,
+                                        min_pid=cfg.min_pid)
+        else:       # score-only waves (e.g. the Pallas kernel path)
+            families = cluster_families(index.size, join.pairs, None,
+                                        scores=scored.scores,
+                                        min_score=cfg.min_score)
     return AllPairsResult(join=join, scored=scored, families=families,
                           index=index)
 

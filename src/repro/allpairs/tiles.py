@@ -50,7 +50,6 @@ aligned with the input pair order.
 from __future__ import annotations
 
 import functools
-import time
 from collections import deque
 from dataclasses import dataclass
 
@@ -64,7 +63,6 @@ from ..align.smith_waterman import (gather_rows, sw_gather_scores,
 from ..core.alphabet import PAD
 from ..kernels.sw import on_tpu
 from ..obs import span, trace_sentinel
-from ..obs.trace import record as record_span
 
 
 @dataclass(frozen=True)
@@ -114,8 +112,9 @@ class WaveConfig:
     pallas_interpret: bool | None = None  # kernel interpret override
                                  # (None = autodetect by backend)
     with_pid: bool = False       # also run the batched PID traceback
-    profile: bool = False        # block after each phase for an accurate
-                                 # gather/DP/drain time split (slower)
+    profile: bool = False        # block inside each ``wave`` span, so
+                                 # the spans split gather/DP/drain time
+                                 # (slower; no overlap)
 
 
 @dataclass(frozen=True)
@@ -129,10 +128,6 @@ class PairScores:
     n_shapes: int                # distinct wave shapes compiled
     ungapped: np.ndarray | None = None  # (P,) int32 prefilter scores
     kept: np.ndarray | None = None      # (P,) bool — pair ran full SW
-    timings: dict | None = None  # coarse phase seconds: host_gather,
-                                 # dispatch (gather/DP issue), drain,
-                                 # prefilter, pid_wave (device DP + H
-                                 # transfer + host traceback combined)
 
     @property
     def n_prefiltered(self) -> int:
@@ -167,6 +162,7 @@ def wave_plan(pairs: np.ndarray, lens: np.ndarray, cfg: WaveConfig):
 # ---------------------------------------------------------------- device side
 @functools.partial(jax.jit, static_argnames=("Lq", "Lr"))
 @trace_sentinel("wave_gather")
+@jax.named_scope("gather")
 def _gather_wave(ids_dev, lens_dev, pi, pj, *, Lq: int, Lr: int):
     return (gather_rows(ids_dev, lens_dev, pi, Lq),
             gather_rows(ids_dev, lens_dev, pj, Lr))
@@ -238,21 +234,24 @@ class _DrainRing:
     """FIFO of in-flight device results. JAX dispatch is async: pushing wave
     n+1 before fetching wave n overlaps its gather+DP with wave n's D2H
     transfer; only when the ring exceeds ``depth`` does the oldest result
-    block on ``np.asarray`` (device_get)."""
+    block on ``np.asarray`` (device_get), inside a ``drain`` span."""
 
-    def __init__(self, depth: int, sink):
+    def __init__(self, depth: int, sink, kind: str):
         self.depth = max(0, depth)
         self.sink = sink                # sink(slots, host_values)
+        self.kind = kind
         self._q: deque = deque()
 
-    def push(self, slots, dev) -> None:
-        self._q.append((slots, dev))
+    def push(self, slots, dev, B: int) -> None:
+        self._q.append((slots, dev, B))
         while len(self._q) > self.depth:
             self._pop()
 
     def _pop(self) -> None:
-        slots, dev = self._q.popleft()
-        self.sink(slots, np.asarray(dev))
+        slots, dev, B = self._q.popleft()
+        with span("drain", cat="allpairs", B=B, kind=self.kind):
+            host = np.asarray(dev)
+        self.sink(slots, host)
 
     def drain(self) -> None:
         while self._q:
@@ -264,8 +263,6 @@ class _WaveStats:
     def __init__(self):
         self.n_waves = 0
         self.shapes: set = set()
-        self.t = {"host_gather": 0.0, "dispatch": 0.0, "drain": 0.0,
-                  "prefilter": 0.0, "pid_wave": 0.0}
 
 
 def _host_gather(ids, lens, pairs, chunk, B, Lq, Lr):
@@ -346,63 +343,53 @@ def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, out,
 
     sharded = (_sharded_wave_fns(tuple(jax.devices()[:ndev]))
                if ndev > 1 else None)
-    ring = _DrainRing(0 if cfg.profile else cfg.inflight, sink)
+    ring = _DrainRing(0 if cfg.profile else cfg.inflight, sink, kind)
     for chunk, B, Lq, Lr in _iter_wave_chunks(sub, lens, cfg, wave_batch,
                                               ndev):
-        t0 = time.perf_counter()
         if dev is None:                     # host-gather (PR 2) path
-            qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
-            t1 = time.perf_counter()
-            stats.t["host_gather"] += t1 - t0
-            record_span("host_gather", t0, t1, cat="allpairs",
-                        B=B, n=len(chunk))
-            t0 = time.perf_counter()
-            res = _score_block(qm, rm, kind, cfg.xdrop, use_pallas, cfg)
-        elif use_pallas:                    # device gather -> Pallas tile
-            pi, pj = _pad_chunk(sub, chunk, B)
-            qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
-                                  jnp.asarray(pj), Lq=Lq, Lr=Lr)
-            res = _score_block(qm, rm, kind, cfg.xdrop, True, cfg)
-        elif sharded is not None:           # SPMD split over the mesh
-            pi, pj = _pad_chunk(sub, chunk, B)
-            sw_fn, ungapped_fn = sharded
-            if kind == "ungapped":
-                res = ungapped_fn(dev[0], dev[1], pi, pj, x=cfg.xdrop,
-                                  Lq=Lq, Lr=Lr)
+            with span("host_gather", cat="allpairs", B=B, n=len(chunk)):
+                qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
+        # the span covers dispatch only: the device's work ends in the
+        # ``drain`` span that fetches it, unless cfg.profile blocks here
+        with span("wave", cat="allpairs", kind=kind, B=B, Lq=Lq, Lr=Lr,
+                  n=len(chunk), spmd=ndev > 1):
+            if dev is None:
+                res = _score_block(qm, rm, kind, cfg.xdrop, use_pallas, cfg)
+            elif use_pallas:                # device gather -> Pallas tile
+                pi, pj = _pad_chunk(sub, chunk, B)
+                qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
+                                      jnp.asarray(pj), Lq=Lq, Lr=Lr)
+                res = _score_block(qm, rm, kind, cfg.xdrop, True, cfg)
+            elif sharded is not None:       # SPMD split over the mesh
+                pi, pj = _pad_chunk(sub, chunk, B)
+                sw_fn, ungapped_fn = sharded
+                if kind == "ungapped":
+                    res = ungapped_fn(dev[0], dev[1], pi, pj, x=cfg.xdrop,
+                                      Lq=Lq, Lr=Lr)
+                else:
+                    res = sw_fn(dev[0], dev[1], pi, pj, Lq=Lq, Lr=Lr,
+                                dp_kernel=cfg.dp_kernel,
+                                gap_mode=cfg.gap_mode,
+                                gap_open=cfg.gap_open,
+                                gap_extend=cfg.gap_extend)
+            elif kind == "ungapped":        # fused gather + scan
+                pi, pj = _pad_chunk(sub, chunk, B)
+                res = _wave_ungapped_device(dev[0], dev[1], pi, pj,
+                                            x=cfg.xdrop, Lq=Lq, Lr=Lr)
             else:
-                res = sw_fn(dev[0], dev[1], pi, pj, Lq=Lq, Lr=Lr,
-                            dp_kernel=cfg.dp_kernel, gap_mode=cfg.gap_mode,
-                            gap_open=cfg.gap_open,
-                            gap_extend=cfg.gap_extend)
-        elif kind == "ungapped":            # fused gather + scan
-            pi, pj = _pad_chunk(sub, chunk, B)
-            res = _wave_ungapped_device(dev[0], dev[1], pi, pj,
-                                        x=cfg.xdrop, Lq=Lq, Lr=Lr)
-        else:
-            pi, pj = _pad_chunk(sub, chunk, B)
-            res = sw_gather_scores(dev[0], dev[1], dev[0], dev[1],
-                                   pi, pj, Lq=Lq, Lr=Lr,
-                                   dp_kernel=cfg.dp_kernel,
-                                   gap_mode=cfg.gap_mode,
-                                   gap_open=cfg.gap_open,
-                                   gap_extend=cfg.gap_extend)
-        if cfg.profile:
-            jax.block_until_ready(res)
-        key = "prefilter" if kind == "ungapped" else "dispatch"
-        t1 = time.perf_counter()
-        stats.t[key] += t1 - t0
-        # dispatch-side duration: device time hides in the drain unless
-        # cfg.profile blocks per wave
-        record_span("wave", t0, t1, cat="allpairs", kind=kind, B=B,
-                    Lq=Lq, Lr=Lr, n=len(chunk), spmd=ndev > 1)
-        t0 = time.perf_counter()
-        ring.push(subset[chunk], res)
-        stats.t["drain"] += time.perf_counter() - t0
+                pi, pj = _pad_chunk(sub, chunk, B)
+                res = sw_gather_scores(dev[0], dev[1], dev[0], dev[1],
+                                       pi, pj, Lq=Lq, Lr=Lr,
+                                       dp_kernel=cfg.dp_kernel,
+                                       gap_mode=cfg.gap_mode,
+                                       gap_open=cfg.gap_open,
+                                       gap_extend=cfg.gap_extend)
+            if cfg.profile:
+                jax.block_until_ready(res)
+        ring.push(subset[chunk], res, B)
         stats.n_waves += 1
         stats.shapes.add((kind, B, Lq, Lr))
-    t0 = time.perf_counter()
     ring.drain()
-    stats.t["drain"] += time.perf_counter() - t0
 
 
 def _run_pid_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev,
@@ -413,24 +400,19 @@ def _run_pid_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev,
     sub = pairs[subset]
     for chunk, B, Lq, Lr in _iter_wave_chunks(sub, lens, cfg,
                                               cfg.wave_batch):
-        t0 = time.perf_counter()
         if dev is None:
-            qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
-            stats.t["host_gather"] += time.perf_counter() - t0
+            with span("host_gather", cat="allpairs", B=B, n=len(chunk)):
+                qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
         else:
             pi, pj = _pad_chunk(sub, chunk, B)
             qmd, rmd = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
                                     jnp.asarray(pj), Lq=Lq, Lr=Lr)
             qm, rm = np.asarray(qmd), np.asarray(rmd)
-            stats.t["dispatch"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        pw, lw, sw = sw_wave_pid(qm, rm, chunk=B)
-        # one bucket for the whole PID wave: device DP + H-matrix D2H +
-        # host traceback (sw_wave_pid interleaves them internally)
-        t1 = time.perf_counter()
-        stats.t["pid_wave"] += t1 - t0
-        record_span("wave", t0, t1, cat="allpairs", kind="pid", B=B,
-                    Lq=Lq, Lr=Lr, n=len(chunk))
+        # the whole PID wave: device DP + H-matrix D2H + host traceback
+        # (sw_wave_pid interleaves them internally)
+        with span("wave", cat="allpairs", kind="pid", B=B, Lq=Lq, Lr=Lr,
+                  n=len(chunk)):
+            pw, lw, sw = sw_wave_pid(qm, rm, chunk=B)
         slots = subset[chunk]
         pid[slots] = pw[:len(chunk)]
         aln[slots] = lw[:len(chunk)]
@@ -464,7 +446,15 @@ def score_pairs(ids: np.ndarray, lens: np.ndarray, pairs: np.ndarray,
     pairs = np.asarray(pairs, np.int32)
     lens = np.asarray(lens, np.int32)
     P = len(pairs)
-    t_all = time.perf_counter()
+    with span("score_pairs", cat="allpairs", pairs=P) as sp:
+        res = _score_pairs(ids, lens, pairs, cfg)
+        sp.set(waves=res.n_waves, shapes=res.n_shapes,
+               prefiltered=res.n_prefiltered)
+    return res
+
+
+def _score_pairs(ids, lens, pairs, cfg: WaveConfig) -> PairScores:
+    P = len(pairs)
     scores = np.zeros(P, np.int32)
     pid = np.zeros(P) if cfg.with_pid else None
     aln = np.zeros(P, np.int64) if cfg.with_pid else None
@@ -500,10 +490,6 @@ def score_pairs(ids: np.ndarray, lens: np.ndarray, pairs: np.ndarray,
             _run_score_waves(ids, lens, pairs, subset, cfg, dev, scores,
                              stats, kind="sw", wave_batch=cfg.wave_batch,
                              use_pallas=use_pallas, ndev=ndev)
-    record_span("score_pairs", t_all, time.perf_counter(), cat="allpairs",
-                pairs=P, waves=stats.n_waves, shapes=len(stats.shapes),
-                prefiltered=0 if kept is None else int((~kept).sum()))
     return PairScores(scores=scores, pid=pid, aln_len=aln,
                       n_waves=stats.n_waves, n_shapes=len(stats.shapes),
-                      ungapped=ungapped, kept=kept,
-                      timings=dict(stats.t))
+                      ungapped=ungapped, kept=kept)

@@ -193,12 +193,15 @@ class ShardedIndex:
         self.index = index
         self.axis_name = axis_name
         if mesh is None:
-            n = jax.device_count()
-            mesh = jax.make_mesh((n,), (axis_name,))
+            mesh = Mesh(np.array(jax.devices()), (axis_name,))
         if axis_name not in mesh.axis_names:
             raise ValueError(f"mesh has axes {mesh.axis_names}, expected "
                              f"{axis_name!r}")
-        self.mesh = mesh
+        # Auto axes, as the ring's own mesh has: a caller's Explicit mesh
+        # (``jax.make_mesh``'s default) would put its axis into the slabs'
+        # types, and jit would trace the same ring program once per kind
+        # of mesh.
+        self.mesh = Mesh(mesh.devices, mesh.axis_names)
         self.n_shards = mesh.shape[axis_name]
         # Serializes this replica's slab swaps AND the backing index's
         # lazy lifecycle mutations (seal/merge/partition) that refresh

@@ -216,12 +216,13 @@ class SignatureIndex:
               key_hash: str = "splitmix",
               n_shards: int = 1) -> "SignatureIndex":
         """Run job 1 (signature generation + validity) over the reference set."""
-        sl = ScalLoPS(cfg)
-        sigs = np.asarray(sl.signatures(ref_ids, ref_lens))
-        valid = np.asarray(sl.feature_counts(ref_ids, ref_lens)) > 0
-        idx = cls(cfg, sigs, valid, layout=layout, bands=bands,
-                  interleave=interleave, key_hash=key_hash,
-                  n_shards=n_shards)
+        with span("index_build", cat="lifecycle", n=len(ref_lens)):
+            sl = ScalLoPS(cfg)
+            sigs = np.asarray(sl.signatures(ref_ids, ref_lens))
+            valid = np.asarray(sl.feature_counts(ref_ids, ref_lens)) > 0
+            idx = cls(cfg, sigs, valid, layout=layout, bands=bands,
+                      interleave=interleave, key_hash=key_hash,
+                      n_shards=n_shards)
         idx._pipeline = sl
         return idx
 

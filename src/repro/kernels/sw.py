@@ -131,8 +131,9 @@ def _wave_call(sk, *, mode: str, bb: int, interpret: bool | None,
     dc = min(DIAG_BLOCK, nd)
     pad = (-nd) % dc
     if pad:
-        sk = jnp.concatenate(
-            [sk, jnp.full((pad, B, Lq), SENT8, jnp.int8)], axis=0)
+        with jax.named_scope("skew"):
+            sk = jnp.concatenate(
+                [sk, jnp.full((pad, B, Lq), SENT8, jnp.int8)], axis=0)
     n_carry = 5 if mode == "affine" or (mode == "ungapped"
                                         and x is not None) else 3
     return pl.pallas_call(
@@ -146,16 +147,21 @@ def _wave_call(sk, *, mode: str, bb: int, interpret: bool | None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
+        # XLA names the kernel's op after this (``ungapped_prefilter.1``),
+        # so a profile tells the kernel from the skew around it
+        name="ungapped_prefilter" if mode == "ungapped" else "wavefront_dp",
     )(sk)
 
 
+@jax.named_scope("skew")
 def _skewed(qs, rs):
     """(B, Lq) x (B, Lr) int8 -> the (nd, B, Lq) int8 skewed substitution
     block the wave kernels sweep, ``sk[c, b, i] = s_b[i, c-i]`` (SENT8 on
     PAD and outside the matrix). The reference is skewed by one gather of
     column ``c - i`` and the query axis resolved by 20 selects. This is
     bit-identical to `align.gotoh`'s pad-reshape skew, which the TPU
-    compiler takes ~25 s per shape to lay out at L=640 (this form: ~2 s)."""
+    compiler takes ~25 s per shape to lay out at L=640 (this form: ~2 s).
+    Its ops sit under the ``skew`` name scope."""
     B, Lq = qs.shape
     Lr = rs.shape[1]
     nd = Lq + Lr - 1

@@ -36,16 +36,28 @@ category    spans
 serve       submit, dispatch, shed, query_batch, ladder, sig, probe, ring,
             rerank, route, resolve, warmup
 lifecycle   seal, refresh, place, compact_serving, ingest, minor_compaction,
-            major_compaction, compact_index
-allpairs    emission, delta_emission, wave, host_gather, score_pairs
+            major_compaction, compact_index, index_build
+allpairs    emission, delta_emission, wave, host_gather, drain, score_pairs,
+            graph
 jit         compile (instant; one per traced program body — see
-            repro.obs.jit)
+            repro.obs.jit); lower (a JAX trace, lowering, backend compile
+            or persistent-cache load, from ``jax.monitoring``)
+runtime     gc (one Python garbage collection, from ``gc.callbacks``)
 ==========  ================================================================
+
+The ``gc`` and ``lower`` spans are host stalls no call site can wrap:
+:meth:`Tracer.enable` installs their hooks (importing JAX then, not when
+this module is imported) and :meth:`Tracer.disable` takes the ``gc``
+callback out again; the JAX listener stays registered and returns at once
+while tracing is off. While a ``jax.profiler`` trace is running, every
+:func:`span` also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+the profile shows the program's spans on the device trace's own clock.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import os
@@ -64,6 +76,19 @@ _TRACE_CTX: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "repro_trace", default=())
 
 _ids = itertools.count(1)       # CPython next() is atomic
+
+#: ``jax.profiler.TraceAnnotation`` once a tracer was enabled (None before:
+#: importing this module must not import JAX)
+_annotation = None
+
+#: ``jax.monitoring`` duration events recorded as ``lower`` spans, by the
+#: ``event`` arg they get: each is host time spent making a program
+_LOWER_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 
 
 def new_trace_id() -> int:
@@ -93,9 +118,13 @@ class Tracer:
     def __init__(self, capacity: int = 65536):
         self.enabled = False
         self._buf: deque = deque(maxlen=int(capacity))
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start between any two
+        # bytecodes, also inside a locked block, and its callback records
+        self._lock = threading.RLock()
         self._t0 = time.perf_counter()      # trace epoch (ts are relative)
         self._dropped = 0
+        self._gc_t0: float | None = None
+        self._jax_hooked = False
 
     # -------------------------------------------------------------- control
     def enable(self, capacity: int | None = None) -> None:
@@ -103,9 +132,42 @@ class Tracer:
             if capacity is not None:
                 self._buf = deque(self._buf, maxlen=int(capacity))
             self.enabled = True
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        if not self._jax_hooked:
+            self._jax_hooked = True
+            global _annotation
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_jax_duration)
+            _annotation = jax.profiler.TraceAnnotation
 
     def disable(self) -> None:
         self.enabled = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    # -------------------------------------------------------------- hooks
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``gc`` span per collection."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            t0, self._gc_t0 = self._gc_t0, None
+            if self.enabled:
+                self.record("gc", "runtime", t0, time.perf_counter(),
+                            {"generation": info["generation"],
+                             "collected": info["collected"]})
+
+    def _on_jax_duration(self, event: str, secs: float, **kw) -> None:
+        """``jax.monitoring`` listener: a ``lower`` span over the last
+        ``secs`` seconds, recorded as the event ends."""
+        if not self.enabled or event not in _LOWER_EVENTS:
+            return
+        t1 = time.perf_counter()
+        self.record("lower", "jit", t1 - secs, t1,
+                    {"event": _LOWER_EVENTS[event],
+                     "fun": kw.get("fun_name")})
 
     def clear(self) -> None:
         with self._lock:
@@ -197,12 +259,15 @@ class _NopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOP = _NopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "attrs", "t0")
+    __slots__ = ("name", "cat", "attrs", "t0", "ann")
 
     def __init__(self, name: str, cat: str, attrs: dict):
         self.name = name
@@ -210,18 +275,29 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self.ann = None
+        if _annotation is not None and _annotation.is_enabled():
+            self.ann = _annotation(self.name)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        TRACER.record(self.name, self.cat, self.t0, time.perf_counter(),
-                      self.attrs)
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        TRACER.record(self.name, self.cat, self.t0, t1, self.attrs)
         return False
+
+    def set(self, **attrs) -> None:
+        """Add args known only at the end of the span's work."""
+        self.attrs.update(attrs)
 
 
 def span(name: str, cat: str = "serve", **attrs):
     """``with span("probe", shard=s): ...`` — records a complete event when
-    tracing is enabled; a shared no-op otherwise (one branch)."""
+    tracing is enabled; a shared no-op otherwise (one branch). ``with
+    span(...) as sp: ...; sp.set(n=...)`` adds args at the end."""
     if not TRACER.enabled:
         return _NOP
     return _Span(name, cat, attrs)

@@ -55,9 +55,10 @@ def index(data):
 
 @pytest.fixture
 def traced():
-    """Tracing on with a clean buffer; always off + cleared afterwards."""
+    """Tracing on with a clean buffer of the default size; always off +
+    cleared afterwards."""
     TRACER.clear()
-    TRACER.enable()
+    TRACER.enable(capacity=65536)
     yield TRACER
     TRACER.disable()
     TRACER.clear()
@@ -179,10 +180,16 @@ def test_trace_context_tags_spans(traced):
 
 
 def test_trace_buffer_bounded(traced):
+    import gc
+
     traced.enable(capacity=64)
-    for i in range(200):
-        with span("x", i=i):
-            pass
+    gc.disable()        # no collection's span among the 200
+    try:
+        for i in range(200):
+            with span("x", i=i):
+                pass
+    finally:
+        gc.enable()
     assert len(traced) == 64
     assert traced.chrome_trace()["otherData"]["dropped_spans"] == 136
 
@@ -229,6 +236,110 @@ def test_shed_resolves_with_reason(index, traced):
     assert any(not o.ok and o.reason == "queue_full" for o in outs)
     sheds = [s for s in traced.spans() if s["name"] == "shed"]
     assert sheds and sheds[0]["args"]["reason"] == "queue_full"
+
+
+
+# ------------------------------------------------- all-pairs and host spans
+def test_all_pairs_spans_nest_inside_the_call(traced):
+    """Index build, join, scoring and graph each get a span inside
+    ``all_pairs_search``; the waves' fetches get ``drain`` spans."""
+    import time
+
+    from repro.allpairs import AllPairsConfig, WaveConfig, all_pairs_search
+    from repro.data import FamilyCorpusConfig, make_family_corpus
+
+    c = make_family_corpus(FamilyCorpusConfig(
+        n_families=6, family_size=3, n_singletons=12, len_mean=80,
+        len_std=10, sub_rate=0.04, seed=3))
+    cfg = AllPairsConfig(lsh=CFG, wave=WaveConfig(prefilter=True))
+    all_pairs_search(c["ids"], c["lens"], cfg)      # compile outside
+    traced.clear()
+    t0 = time.perf_counter() - traced._t0
+    all_pairs_search(c["ids"], c["lens"], cfg)
+    t1 = time.perf_counter() - traced._t0
+    spans = traced.spans()
+    top = {}
+    for name in ("index_build", "emission", "score_pairs", "graph"):
+        got = [sp for sp in spans if sp["name"] == name]
+        assert len(got) == 1, name
+        top[name] = got[0]
+        assert t0 <= got[0]["ts"] and got[0]["ts"] + got[0]["dur"] <= t1
+    assert top["index_build"]["cat"] == "lifecycle"
+    order = sorted(top, key=lambda n: top[n]["ts"])
+    assert order == ["index_build", "emission", "score_pairs", "graph"]
+    assert sum(sp["dur"] for sp in top.values()) < t1 - t0
+    sp = top["score_pairs"]
+    waves = [w for w in spans if w["name"] == "wave"]
+    drains = [d for d in spans if d["name"] == "drain"]
+    assert len(waves) == len(drains) == sp["args"]["waves"] > 0
+    assert {w["args"]["kind"] for w in waves} == \
+        {d["args"]["kind"] for d in drains} == {"ungapped", "sw"}
+    assert all({"B", "Lq", "Lr"} <= set(w["args"]) for w in waves)
+    for x in waves + drains:
+        assert sp["ts"] <= x["ts"] and \
+            x["ts"] + x["dur"] <= sp["ts"] + sp["dur"]
+
+
+def test_gc_collect_records_a_gc_span(traced):
+    import gc
+
+    gc.collect()
+    got = [sp for sp in traced.spans() if sp["name"] == "gc"]
+    assert got and got[-1]["cat"] == "runtime"
+    assert got[-1]["args"]["generation"] == 2
+    assert got[-1]["dur"] >= 0 and "collected" in got[-1]["args"]
+
+
+def test_fresh_jit_records_a_lower_span(traced):
+    import jax
+
+    jax.jit(lambda x: x * 3 - 1)(np.arange(5, dtype=np.int32))
+    got = [sp for sp in traced.spans() if sp["name"] == "lower"]
+    assert got and all(sp["cat"] == "jit" for sp in got)
+    assert {"trace", "lower"} <= {sp["args"]["event"] for sp in got}
+    assert all(sp["dur"] >= 0 for sp in got)
+
+
+def test_tracing_off_records_nothing_and_hooks_no_gc():
+    import gc
+
+    import jax
+
+    TRACER.enable()
+    TRACER.disable()
+    TRACER.clear()
+    assert TRACER._on_gc not in gc.callbacks
+    gc.collect()
+    jax.jit(lambda x: x + 7)(np.arange(3, dtype=np.int32))
+    with span("probe", B=1):
+        pass
+    assert len(TRACER) == 0
+
+
+def test_span_under_a_profiler_trace_opens_an_annotation(traced, tmp_path):
+    """With the profiler running, a span also lands in the profile as a
+    TraceMe event of its own name, on the profiler's clock."""
+    import glob
+
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("obs_test_annotated", cat="allpairs"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    with span("obs_test_after", cat="allpairs"):    # no profiler: no event
+        pass
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    names = {ev.name
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "obs_test_annotated" in names
+    assert "obs_test_after" not in names
+    assert {sp["name"] for sp in traced.spans()} >= {"obs_test_annotated",
+                                                     "obs_test_after"}
 
 
 # ---------------------------------------------------------------- metrics glue

@@ -12,6 +12,7 @@ the scoped VMEM limit. Nothing runs, so results are the other tests' job.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,15 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+def _kernel_op(text: str, name: str) -> bool:
+    """True iff the compiled program holds a Pallas kernel whose op XLA
+    named after ``name`` (``%name.1 = ... custom-call(...)``), the name
+    the benchmark's profile readers look for."""
+    return re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", text) \
+        is not None
+
+
 def _compiled_text(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile().as_text()
@@ -68,6 +78,7 @@ def test_wave_scores_kernel_compiles(one_chip, gap_mode, L):
                                         interpret=False),
         one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
     assert "tpu_custom_call" in text
+    assert _kernel_op(text, "wavefront_dp")
 
 
 @pytest.mark.parametrize("L,x", [(128, None), (384, None), (384, 20),
@@ -78,6 +89,7 @@ def test_ungapped_scores_kernel_compiles(one_chip, L, x):
         lambda q, r: ungapped_scores_kernel(q, r, x=x, interpret=False),
         one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
     assert "tpu_custom_call" in text
+    assert _kernel_op(text, "ungapped_prefilter")
 
 
 # (G bands, U+1 offsets, E entries, cap) of the NC_000913-shaped corpus
