@@ -7,14 +7,14 @@ subsystem computes the corpus similarity graph on top of the persistent LSH
 index:
 
   corpus -> SignatureIndex.build -> LSH self-join (within-bucket pairs,
-  deduped, upper-triangular CSR) -> tiled pair scheduler (length-bucketed
-  fixed-shape waves) -> batched Smith-Waterman row-wave scoring (+ PID)
+  deduped, upper-triangular CSR) -> pair scheduler (fixed-shape waves keyed
+  by padded length) -> batched Smith-Waterman row-wave scoring (+ PID)
   -> similarity graph -> union-find connected components = protein families
 
 * ``selfjoin`` — :func:`lsh_self_join`: exact band-collision enumeration
   with the grow-and-retry capacity discipline; CSR adjacency output.
-* ``tiles``   — :func:`score_pairs`: (tile_i, tile_j) blocks, padded-length
-  ladder, *device-resident* batched SW waves — fused on-device gathers
+* ``tiles``   — :func:`score_pairs`: waves keyed by padded-length shape
+  alone, *device-resident* batched SW waves — fused on-device gathers
   (corpus uploaded once, per-wave H2D is just pair indices), an optional
   ungapped X-drop prefilter that skips full DP for hopeless pairs, and
   async double-buffered dispatch drained through a small in-flight ring

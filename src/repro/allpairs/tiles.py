@@ -1,4 +1,4 @@
-"""Tiled pair scheduler: candidate pairs -> device-resident batched SW waves.
+"""Pair scheduler: candidate pairs -> device-resident batched SW waves.
 
 At corpus scale the candidate set of the self-join is far too ragged to
 score naively: pair lengths vary, per-pair DP calls retrace the jit cache
@@ -6,30 +6,29 @@ for every new (Lq, Lr), and any host work between dispatches leaves the
 device idle. The scheduler imposes structure — and keeps the whole hot path
 on device:
 
-1. **(tile_i, tile_j) blocks** — pairs are grouped by the corpus tile of
-   each endpoint (tile size ~ device-memory budget for gathered sequences),
-   and blocks are walked in order, so the working set of gathered rows is
-   bounded by two tiles regardless of corpus size.
-2. **length buckets** — within a block, pairs are bucketed by their padded
-   (Lq, Lr) on a quantized ladder (same idea as ``QueryEngine``'s padding
-   ladder: a small, closed set of shapes keeps the jit cache stable).
-3. **fused device gather** — the padded corpus ``(N, Lmax)`` is uploaded
+1. **padded-shape waves** — pairs are keyed by their padded (Lq, Lr) on
+   a quantized ladder (same idea as ``QueryEngine``'s padding ladder: a
+   small, closed set of shapes keeps the jit cache stable), over the whole
+   pair set, so each shape's bucket fills ``ceil(m / B)`` waves and only
+   its last wave carries padding. The gather reads the corpus uploaded
+   once (point 2), so pairs from any rows can share a wave.
+2. **fused device gather** — the padded corpus ``(N, Lmax)`` is uploaded
    ONCE; each wave is one jitted take-and-mask program over pair index
    arrays (``ids[pair_idx, :Lq]``), so the only per-wave H2D traffic is the
    (B,) index vectors — no per-pair host copy loop
    (``device_gather=False`` restores the PR 2 host path, bit-exact).
-4. **ungapped X-drop prefilter** (``prefilter=True``) — every wave first
+3. **ungapped X-drop prefilter** (``prefilter=True``) — every wave first
    runs a cheap ungapped diagonal scan (BLAST-style X-drop extension, an
    elementwise DP with no within-row prefix scan); only pairs whose
    ungapped score reaches ``prefilter_min`` proceed to the full gapped
    wave. The ungapped score is a *lower bound* of the SW score, so the
    filter never adds pairs; rejected pairs report their ungapped score
    (``kept`` marks the survivors, whose scores are full SW, bit-exact).
-5. **async double-buffered dispatch** — wave n+1's gather+DP is issued
+4. **async double-buffered dispatch** — wave n+1's gather+DP is issued
    while wave n's scores are still in flight; a small FIFO ring
    (``inflight``) drains ``device_get`` results, so wall-clock tracks
    device DP time instead of Python dispatch.
-6. **multi-device waves** (``n_devices > 1``) — each wave batch is split
+5. **multi-device waves** (``n_devices > 1``) — each wave batch is split
    over the first ``n_devices`` of ``jax.devices()`` as ONE SPMD program
    (``shard_map``: pair index vectors partitioned, corpus replicated), so
    ``n_devices`` pair blocks gather+score concurrently per dispatch — the
@@ -67,7 +66,6 @@ from ..obs import span, trace_sentinel
 
 @dataclass(frozen=True)
 class WaveConfig:
-    tile: int = 1024             # corpus rows per (tile_i, tile_j) block
     wave_batch: int = 64         # pairs per full-SW wave (upper bound)
     len_quantum: int = 64        # pad pair lengths to multiples of this
     max_wave_cells: int = 1 << 23  # B*Lq*Lr budget; shrinks B for long pairs
@@ -139,24 +137,22 @@ def _quantize(lens: np.ndarray, quantum: int) -> np.ndarray:
 
 
 def wave_plan(pairs: np.ndarray, lens: np.ndarray, cfg: WaveConfig):
-    """Group pair indices into dispatch order: (tile_i, tile_j) block, then
-    padded-length bucket. Yields (pair_idx (m,), Lq_pad, Lr_pad) with
-    pair_idx referring to rows of ``pairs``."""
+    """Group pair indices into dispatch order by padded-length bucket.
+    Yields (pair_idx (m,), Lq_pad, Lr_pad) with pair_idx referring to rows
+    of ``pairs``, in input order within a bucket."""
     if len(pairs) == 0:
         return
-    ti = pairs[:, 0] // cfg.tile
-    tj = pairs[:, 1] // cfg.tile
     lq = _quantize(lens[pairs[:, 0]], cfg.len_quantum)
     lr = _quantize(lens[pairs[:, 1]], cfg.len_quantum)
-    # dispatch key: block-major, then shape; lexsort is stable so pairs stay
-    # in input order within a wave
-    order = np.lexsort((lr, lq, tj, ti))
-    keys = np.stack([ti[order], tj[order], lq[order], lr[order]], axis=1)
+    # dispatch key: the padded shape; lexsort is stable so pairs stay in
+    # input order within a wave
+    order = np.lexsort((lr, lq))
+    keys = np.stack([lq[order], lr[order]], axis=1)
     starts = np.flatnonzero(
         np.concatenate([[True], (np.diff(keys, axis=0) != 0).any(axis=1)]))
     bounds = np.concatenate([starts, [len(order)]])
     for s, e in zip(bounds[:-1], bounds[1:]):
-        yield order[s:e], int(keys[s, 2]), int(keys[s, 3])
+        yield order[s:e], int(keys[s, 0]), int(keys[s, 1])
 
 
 # ---------------------------------------------------------------- device side

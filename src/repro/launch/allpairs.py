@@ -6,7 +6,7 @@
       [--incremental 128]
 
 Builds (or loads, --index) the corpus SignatureIndex, runs the LSH
-self-join, scores the candidate pairs with device-resident tiled
+self-join, scores the candidate pairs with device-resident
 Smith-Waterman waves (fused gather + ungapped X-drop prefilter + async
 drain ring), and clusters the thresholded similarity graph into families.
 
@@ -94,7 +94,6 @@ def main(argv=None):
                          "devices as one SPMD program; the PID traceback "
                          "wave (the default scoring mode here) is "
                          "host-bound and stays single-device")
-    ap.add_argument("--tile", type=int, default=1024)
     ap.add_argument("--wave-batch", type=int, default=64)
     ap.add_argument("--pallas", action="store_true",
                     help="score waves with the Pallas SW tile kernel "
@@ -181,7 +180,7 @@ def main(argv=None):
         lsh=lsh, hamming_filter=not args.no_hamming_filter,
         min_pid=args.min_pid, min_score=args.min_score,
         n_shards=args.shards,
-        wave=WaveConfig(tile=args.tile, wave_batch=args.wave_batch,
+        wave=WaveConfig(wave_batch=args.wave_batch,
                         use_pallas=args.pallas or None,
                         with_pid=not args.pallas,
                         device_gather=not args.host_gather,

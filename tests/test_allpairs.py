@@ -11,10 +11,12 @@ from repro.align.smith_waterman import (percent_identity, sw_align_batch,
 from repro.allpairs import (AllPairsConfig, WaveConfig, all_pairs_search,
                             brute_force_collisions, cluster_families,
                             lsh_self_join, score_pairs, union_find)
+from repro.allpairs.tiles import _iter_wave_chunks
 from repro.core import LSHConfig
 from repro.core.alphabet import PAD
 from repro.data import FamilyCorpusConfig, make_family_corpus
 from repro.index import SignatureIndex
+from repro.kernels.ref import ungapped_xdrop_ref
 
 CFG = LSHConfig(k=3, T=13, f=32, d=1)
 
@@ -313,6 +315,108 @@ def test_wave_pallas_kernel_parity(corpus):
     b = score_pairs(ids, lens, pairs,
                     WaveConfig(wave_batch=4, use_pallas=True))
     np.testing.assert_array_equal(a.scores, b.scores)
+
+
+# ---------------------------------------------------------------- wave plan
+def _shape_buckets(pairs, lens, cfg):
+    """{(Lq, Lr): pair count} of the padded-length ladder."""
+    q = cfg.len_quantum
+    lq = np.maximum(q, -(-lens[pairs[:, 0]] // q) * q)
+    lr = np.maximum(q, -(-lens[pairs[:, 1]] // q) * q)
+    keys, counts = np.unique(np.stack([lq, lr], 1), axis=0,
+                             return_counts=True)
+    return {(int(a), int(b)): int(m) for (a, b), m in zip(keys, counts)}
+
+
+def _expected_waves(pairs, lens, cfg, batch, ndev=1):
+    """Waves and shapes a plan keyed by padded shape alone makes: each
+    bucket of m pairs fills ceil(m / B) waves of the cell-budget batch."""
+    buckets = _shape_buckets(pairs, lens, cfg)
+    waves = sum(-(-m // (max(1, min(batch, cfg.max_wave_cells // (a * b)))
+                         * ndev))
+                for (a, b), m in buckets.items())
+    return waves, len(buckets)
+
+
+@pytest.mark.parametrize("wave_batch, ndev", [(64, 1), (256, 1), (64, 4)])
+def test_wave_plan_packs_each_shape_across_the_corpus(wave_batch, ndev):
+    """Rows far apart (ids more than 1,024 apart in a ~3,000-row corpus)
+    share a wave when their padded shape matches: every pair is planned
+    once, each (Lq, Lr) bucket fills exactly ceil(m / B) waves, and pairs
+    keep their input order within a wave."""
+    rng = np.random.default_rng(14)
+    n = 3000
+    lens = rng.integers(20, 420, n).astype(np.int32)
+    i = rng.integers(0, n - 1100, 4000)
+    j = i + rng.integers(1025, n - i)
+    pairs = np.stack([i, j], 1).astype(np.int32)
+    pairs[::2] = pairs[::2, ::-1]           # both orders of the endpoints
+    cfg = WaveConfig()
+    chunks = list(_iter_wave_chunks(pairs, lens, cfg, wave_batch, ndev))
+    seen = np.concatenate([c for c, *_ in chunks])
+    np.testing.assert_array_equal(np.sort(seen), np.arange(len(pairs)))
+    by_shape: dict = {}
+    for chunk, B, Lq, Lr in chunks:
+        assert (np.diff(chunk) > 0).all(), "input order within a wave"
+        assert 0 < len(chunk) <= B
+        by_shape.setdefault((Lq, Lr), []).append((len(chunk), B))
+    buckets = _shape_buckets(pairs, lens, cfg)
+    assert by_shape.keys() == buckets.keys()
+    for shape, waves in by_shape.items():
+        m, B = buckets[shape], waves[0][1]
+        assert len(waves) == -(-m // B)
+        assert all(b == B for _, b in waves)
+        assert all(k == B for k, _ in waves[:-1]), "only the last is short"
+    assert len(chunks) == _expected_waves(pairs, lens, cfg, wave_batch,
+                                          ndev)[0]
+
+
+def test_score_pairs_wave_count_is_the_shape_plan(corpus):
+    """``n_waves`` / ``n_shapes`` of a prefiltered run on a ragged corpus
+    are the shape-keyed plan's count: prefilter waves over every pair,
+    DP waves over the survivors."""
+    ids, lens = corpus["ids"], corpus["lens"]
+    pairs = _random_pairs(corpus, 96, 14)
+    cfg = WaveConfig(wave_batch=8, prefilter_batch=16, prefilter=True,
+                     prefilter_min=40)
+    res = score_pairs(ids, lens, pairs, cfg)
+    pw, ps = _expected_waves(pairs, lens, cfg, cfg.prefilter_batch)
+    dw, ds = _expected_waves(pairs[res.kept], lens, cfg, cfg.wave_batch)
+    assert 0 < res.kept.sum() < len(pairs)
+    assert (res.n_waves, res.n_shapes) == (pw + dw, ps + ds)
+
+
+def test_prefilter_waves_across_row_1024_bitexact(corpus):
+    """Pairs whose rows lie on both sides of row 1,024 share waves; their
+    prefilter and DP scores equal the per-pair references."""
+    ids, lens = corpus["ids"], corpus["lens"]
+    n, L = ids.shape
+    short = np.full((1000, L), PAD, np.int8)
+    short[:, :8] = np.arange(8000).reshape(1000, 8) % 20
+    big_ids = np.concatenate([ids, short, ids])
+    big_lens = np.concatenate([lens, np.full(1000, 8, np.int32), lens])
+    far = n + 1000                           # the copy of row 0, past 1,024
+    assert far > 1024
+    rng = np.random.default_rng(15)
+    a = rng.integers(0, n, 40)
+    pairs = np.concatenate([
+        np.stack([a, far + a], 1),           # a row and its own copy
+        np.stack([a, far + rng.integers(0, n, 40)], 1),
+        np.stack([far + rng.integers(0, n, 8), rng.integers(0, n, 8)], 1),
+        np.stack([rng.integers(0, n, 8), rng.integers(0, n, 8)], 1),
+        np.stack([rng.integers(n, far, 8), far + rng.integers(0, n, 8)], 1),
+    ]).astype(np.int32)
+    res = score_pairs(big_ids, big_lens, pairs,
+                      WaveConfig(wave_batch=8, prefilter=True,
+                                 prefilter_min=40))
+    assert 0 < res.kept.sum() < len(pairs)
+    for row, (i, j) in enumerate(pairs):
+        q, r = big_ids[i, :big_lens[i]], big_ids[j, :big_lens[j]]
+        assert res.ungapped[row] == ungapped_xdrop_ref(q, r, x=1 << 30)
+        if res.kept[row]:
+            assert res.scores[row] == sw_score(q, r)
+        else:
+            assert res.scores[row] == res.ungapped[row]
 
 
 # ---------------------------------------------------------------- clustering
