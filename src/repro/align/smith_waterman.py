@@ -325,13 +325,14 @@ def sw_wave_pid(qs, rs, *, chunk: int = 32):
     qs (N, Lq) x rs (N, Lr) int8, PAD-padded (padding only ever suffixes a
     sequence, so the real subgrid of each padded DP matrix — and its argmax
     cell in row-major order — is identical to the unpadded one; results are
-    bit-exact with :func:`percent_identity` on the unpadded pair).
+    bit-exact with :func:`percent_identity` on the unpadded pair). Device
+    arrays are scored where they are; only the walk copies them to host.
 
     Returns (pid (N,) float64, length (N,) int64, score (N,) int64).
     All-PAD rows (wave padding) score 0 with pid 0, length 0.
     """
-    qs = np.asarray(qs, np.int8)
-    rs = np.asarray(rs, np.int8)
+    if not isinstance(qs, jax.Array):
+        qs, rs = np.asarray(qs, np.int8), np.asarray(rs, np.int8)
     N = qs.shape[0]
     pid = np.zeros(N)
     length = np.zeros(N, np.int64)
@@ -340,6 +341,7 @@ def sw_wave_pid(qs, rs, *, chunk: int = 32):
     for i in range(0, N, chunk):
         qc, rc = qs[i:i + chunk], rs[i:i + chunk]
         sc, H = _sw_batch_with_matrix(jnp.asarray(qc), jnp.asarray(rc))
+        qc, rc = np.asarray(qc), np.asarray(rc)
         Hn = np.asarray(H)
         sc = np.asarray(sc)
         for n in range(len(qc)):

@@ -43,8 +43,10 @@ on device:
                            (one jitted program per wave shape,
                             split P("wave") over n_devices)
 
-Scores (and optionally PID via the batched wave + host traceback) come back
-aligned with the input pair order.
+Scores (and optionally PID: on TPU the wavefront kernel's PID mode, which
+tracks each alignment's identities and length through the sweep; off-TPU
+the row wave's DP matrices and the host traceback) come back aligned with
+the input pair order.
 """
 from __future__ import annotations
 
@@ -78,15 +80,18 @@ class WaveConfig:
                                  # to jax.device_count(); needs
                                  # device_gather; wave_batch becomes the
                                  # PER-DEVICE batch). Ignored by the
-                                 # Pallas and PID paths (kernel resp. host
-                                 # traceback stay single-device).
+                                 # Pallas waves (PID ones included) and
+                                 # the host PID route: both stay
+                                 # single-device.
     dp_kernel: str = "wavefront"  # score-only DP sweep: "wavefront" (the
                                  # anti-diagonal Gotoh sweep of
                                  # `align.gotoh`, ~2.8x on CPU) or
                                  # "rowwave" (the int32 prefix-scan row
-                                 # wave, linear-gap fallback). The PID
-                                 # path always uses the row wave (its
-                                 # traceback needs the DP matrix).
+                                 # wave, linear-gap fallback). PID waves
+                                 # take the wavefront kernel's PID mode
+                                 # with use_pallas, else the row wave
+                                 # (the host traceback reads its DP
+                                 # matrices).
     gap_mode: str = "linear"     # gap model: "linear" (GAP = -4, both
                                  # kernels, scores bit-exact across them)
                                  # or "affine" (Gotoh open/extend,
@@ -101,15 +106,14 @@ class WaveConfig:
                                  # run-best carry drops out of the scan)
     prefilter_batch: int = 256   # pairs per prefilter wave (the ungapped
                                  # scan is elementwise, so it batches wider)
-    use_pallas: bool | None = None  # route score-only waves through the
-                                 # Pallas tile kernel; None = auto (TPU
-                                 # only — interpret mode is slower than the
-                                 # jnp wave off-TPU). Ignored with with_pid
-                                 # (the PID traceback needs the DP matrices,
-                                 # which only the jnp wave materializes).
+    use_pallas: bool | None = None  # route waves (score-only and PID)
+                                 # through the Pallas tile kernels; None =
+                                 # auto (TPU only — interpret mode is
+                                 # slower than the jnp wave off-TPU)
     pallas_interpret: bool | None = None  # kernel interpret override
                                  # (None = autodetect by backend)
-    with_pid: bool = False       # also run the batched PID traceback
+    with_pid: bool = False       # also compute each pair's PID and
+                                 # alignment length (linear gaps only)
     profile: bool = False        # block inside each ``wave`` span, so
                                  # the spans split gather/DP/drain time
                                  # (slower; no overlap)
@@ -284,9 +288,12 @@ def _pad_chunk(pairs, chunk, B):
 def _score_block(qm, rm, kind: str, x: int | None, use_pallas: bool,
                  cfg: WaveConfig):
     """Score one assembled (B, Lq) x (B, Lr) block on device, routed by
-    ``cfg.dp_kernel`` / ``cfg.gap_mode`` (see WaveConfig)."""
+    ``cfg.dp_kernel`` / ``cfg.gap_mode`` (see WaveConfig). ``kind="pid"``
+    takes the Pallas PID kernel: (B, 3) score, identities, length."""
     if use_pallas:
         from ..kernels import ops
+        if kind == "pid":
+            return ops.wavefront_pid(qm, rm, interpret=cfg.pallas_interpret)
         if kind == "ungapped":
             return ops.ungapped_wave_scores(qm, rm, x=x,
                                             interpret=cfg.pallas_interpret)
@@ -325,18 +332,22 @@ def _iter_wave_chunks(sub, lens, cfg: WaveConfig, wave_batch: int,
             yield idx[s:s + B], B, Lq, Lr
 
 
-def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, out,
-                     stats: _WaveStats, *, kind: str, wave_batch: int,
-                     use_pallas: bool, ndev: int = 1) -> None:
-    """Dispatch score-only waves (``kind``: "sw" | "ungapped") over
-    ``pairs[subset]``, writing results into ``out[subset[...]]`` through
-    the async drain ring. With ``ndev > 1`` each wave is one SPMD program
-    splitting its batch over the mesh (``_sharded_wave_fns``)."""
-    sub = pairs[subset]
-
+def _store(out):
+    """Drain sink writing a wave's results into ``out`` by pair slot."""
     def sink(slots, host):
         out[slots] = host[:len(slots)]
+    return sink
 
+
+def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, sink,
+                     stats: _WaveStats, *, kind: str, wave_batch: int,
+                     use_pallas: bool, ndev: int = 1) -> None:
+    """Dispatch waves (``kind``: "sw" | "ungapped", or "pid" through the
+    Pallas PID kernel) over ``pairs[subset]``, handing each wave's results
+    to ``sink(slots, host)`` through the async drain ring. With
+    ``ndev > 1`` each wave is one SPMD program splitting its batch over
+    the mesh (``_sharded_wave_fns``)."""
+    sub = pairs[subset]
     sharded = (_sharded_wave_fns(tuple(jax.devices()[:ndev]))
                if ndev > 1 else None)
     ring = _DrainRing(0 if cfg.profile else cfg.inflight, sink, kind)
@@ -390,9 +401,10 @@ def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, out,
 
 def _run_pid_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev,
                    scores, pid, aln, stats: _WaveStats) -> None:
-    """PID waves: batched DP (+ matrices) then the host traceback. The
-    traceback is host-bound either way, so this path drains synchronously;
-    the device gather still removes the per-pair copy loop."""
+    """Host PID route (off-TPU, the definition the kernel is tested
+    against): the row wave's DP matrices, then the host traceback. The
+    traceback is host-bound, so this route drains synchronously; the
+    device gather still removes the per-pair copy loop."""
     sub = pairs[subset]
     for chunk, B, Lq, Lr in _iter_wave_chunks(sub, lens, cfg,
                                               cfg.wave_batch):
@@ -401,9 +413,8 @@ def _run_pid_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev,
                 qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
         else:
             pi, pj = _pad_chunk(sub, chunk, B)
-            qmd, rmd = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
-                                    jnp.asarray(pj), Lq=Lq, Lr=Lr)
-            qm, rm = np.asarray(qmd), np.asarray(rmd)
+            qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
+                                  jnp.asarray(pj), Lq=Lq, Lr=Lr)
         # the whole PID wave: device DP + H-matrix D2H + host traceback
         # (sw_wave_pid interleaves them internally)
         with span("wave", cat="allpairs", kind="pid", B=B, Lq=Lq, Lr=Lr,
@@ -456,11 +467,11 @@ def _score_pairs(ids, lens, pairs, cfg: WaveConfig) -> PairScores:
     aln = np.zeros(P, np.int64) if cfg.with_pid else None
     stats = _WaveStats()
     use_pallas = (cfg.use_pallas if cfg.use_pallas is not None
-                  else (on_tpu() and not cfg.with_pid))
+                  else on_tpu())
     dev = ((jnp.asarray(ids), jnp.asarray(lens))
            if cfg.device_gather and P else None)
     # SPMD wave split: only the jnp score/prefilter waves shard (the Pallas
-    # kernel and the PID traceback stay single-device)
+    # kernels and the host PID route stay single-device)
     ndev = 1
     if dev is not None and not use_pallas:
         ndev = max(1, min(cfg.n_devices, jax.device_count()))
@@ -471,20 +482,31 @@ def _score_pairs(ids, lens, pairs, cfg: WaveConfig) -> PairScores:
     subset = everything
     if cfg.prefilter and P:
         ungapped = np.zeros(P, np.int32)
-        _run_score_waves(ids, lens, pairs, everything, cfg, dev, ungapped,
-                         stats, kind="ungapped",
+        _run_score_waves(ids, lens, pairs, everything, cfg, dev,
+                         _store(ungapped), stats, kind="ungapped",
                          wave_batch=cfg.prefilter_batch,
                          use_pallas=use_pallas, ndev=ndev)
         kept = ungapped >= cfg.prefilter_min
         scores[:] = ungapped        # lower bound for the rejected pairs
         subset = np.flatnonzero(kept)
     if len(subset):
-        if cfg.with_pid:
+        if cfg.with_pid and use_pallas:
+            def sink(slots, host):
+                n = len(slots)
+                scores[slots] = host[:n, 0]
+                aln[slots] = host[:n, 2]
+                pid[slots] = 100.0 * host[:n, 1] / np.maximum(host[:n, 2], 1)
+
+            _run_score_waves(ids, lens, pairs, subset, cfg, dev, sink,
+                             stats, kind="pid", wave_batch=cfg.wave_batch,
+                             use_pallas=True)
+        elif cfg.with_pid:
             _run_pid_waves(ids, lens, pairs, subset, cfg, dev,
                            scores, pid, aln, stats)
         else:
-            _run_score_waves(ids, lens, pairs, subset, cfg, dev, scores,
-                             stats, kind="sw", wave_batch=cfg.wave_batch,
+            _run_score_waves(ids, lens, pairs, subset, cfg, dev,
+                             _store(scores), stats, kind="sw",
+                             wave_batch=cfg.wave_batch,
                              use_pallas=use_pallas, ndev=ndev)
     return PairScores(scores=scores, pid=pid, aln_len=aln,
                       n_waves=stats.n_waves, n_shapes=len(stats.shapes),

@@ -25,7 +25,7 @@ from .hamming import hamming_count_kernel, hamming_dist_kernel
 from .siggen import siggen_accumulate_kernel
 from .spgemm import DEFAULT_SLOT_BLOCK
 from .sw import (on_tpu, resolve_interpret, sw_scores_kernel,
-                 ungapped_scores_kernel, wave_scores_kernel)
+                 ungapped_scores_kernel, wave_pid_kernel, wave_scores_kernel)
 
 # Largest max(U+1, slot block) x E working set the emission kernel is
 # given. Compiled for v5e: 512 x 32768 compiles in ~20 s, 512 x 131072
@@ -122,6 +122,22 @@ def wavefront_scores(qs, rs, *, gap_mode: str = "linear",
                              gap_extend=gap_extend, bb=bb,
                              interpret=resolve_interpret(interpret))
     return out[:B, 0]
+
+
+def wavefront_pid(qs, rs, *, bb: int = 8,
+                  interpret: bool | None = None) -> jnp.ndarray:
+    """Batched SW score, identities and alignment length for a (B, Lq) x
+    (B, Lr) pair block via the wavefront kernel's PID mode (padded +
+    cropped) -> (B, 3) int32, on device. Bit-exact with
+    `align.smith_waterman.sw_wave_pid` (the jnp row wave and host walk,
+    which the all-pairs scheduler keeps off-TPU): PID is
+    ``100 * ident / max(length, 1)``. Linear gap GAP, as the walk's rule
+    reads it."""
+    qp, B = _pad_rows(jnp.asarray(qs), bb, value=PAD)
+    rp, _ = _pad_rows(jnp.asarray(rs), bb, value=PAD)
+    out = wave_pid_kernel(qp, rp, bb=bb,
+                          interpret=resolve_interpret(interpret))
+    return out[:B]
 
 
 def ungapped_wave_scores(qs, rs, *, x: int | None = 20, bb: int = 8,

@@ -1,6 +1,6 @@
 """Pallas TPU kernels: batched Smith-Waterman over a pair block — the
-anti-diagonal (wavefront) Gotoh sweep, the ungapped X-drop prefilter, and
-the legacy row wave.
+anti-diagonal (wavefront) Gotoh sweep, its percent-identity mode, the
+ungapped X-drop prefilter, and the legacy row wave.
 
 The all-pairs tiler's inner loop (`repro.allpairs.tiles`): score a block of
 (query, reference) pairs in one program. The wavefront and ungapped
@@ -9,10 +9,11 @@ kernels share one body over the skewed substitution block of
 every DP cell's predecessors sit on the two previous anti-diagonals and
 one diagonal step is elementwise arithmetic over (bb, Lq) lanes — the
 gapped step is the wavefront recurrence, the ungapped step keeps only the
-diagonal move with BLAST's X-drop restart. The grid is (pair block,
-diagonal block); the DP carries live in VMEM scratch across the diagonal
-axis, so VMEM holds one (dc, bb, Lq) int8 slice of the skewed block and
-the carries, whatever the pair length.
+diagonal move with BLAST's X-drop restart, and the PID step also carries
+the identities and length of the walk back from each cell. The grid is
+(pair block, diagonal block); the DP carries live in VMEM scratch across
+the diagonal axis, so VMEM holds one (dc, bb, Lq) int8 slice of the
+skewed block and the carries, whatever the pair length.
 
 Everything is written in the subset Mosaic lowers: the per-diagonal
 substitution row is a dynamic index on the ref's leading axis
@@ -36,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -64,18 +66,23 @@ def _shift_right(v, lane0):
     return jnp.where(lane0, 0, pltpu.roll(v, 1, 1))
 
 
-def _wave_kernel(sk_ref, out_ref, *carry, mode: str, gap_open: int,
+def _wave_kernel(sk_ref, *refs, mode: str, gap_open: int,
                  gap_extend: int, x: int | None, dc: int):
-    """``dc`` diagonals of one (bb,) pair block. ``carry[0]`` is the
-    running best; the rest are the mode's DP lanes, all zero at the first
-    diagonal block and written back to VMEM scratch after each one."""
+    """``dc`` diagonals of one (bb,) pair block. ``refs`` are the outputs
+    (the best score; in ``pid`` mode also the packed walk of the best
+    cell), then the carries: ``carry[0]`` is the running best, the rest
+    the mode's DP lanes, all zero at the first diagonal block and written
+    back to VMEM scratch after each one."""
+    n_out = 2 if mode == "pid" else 1
+    out_ref, carry = refs[0], refs[n_out:]
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         for ref in carry:
             ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, carry[0].shape, 1) == 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, carry[0].shape, 1)
+    lane0 = lane == 0
 
     def shift(v):
         return _shift_right(v, lane0)
@@ -88,6 +95,21 @@ def _wave_kernel(sk_ref, out_ref, *carry, mode: str, gap_open: int,
             h = jnp.maximum(jnp.maximum(h2s + s, 0),
                             jnp.maximum(h1, h1s) + gap_open)
             return jnp.maximum(best, h), h, h1s
+        if mode == "pid":
+            # s = 2 * score + match (`_PID_TABLE`); t packs the walk that
+            # starts at a cell as ident << 16 | length, and bt the walk of
+            # each lane's first best cell
+            best, h1, h2s, t1, t2s, bt = st
+            h1s, t1s = shift(h1), shift(t1)
+            diag = h2s + (s >> 1)
+            up = h1s + gap_open
+            h = jnp.maximum(jnp.maximum(diag, 0),
+                            jnp.maximum(h1 + gap_open, up))
+            t = jnp.where(h == diag, t2s + ((s & 1) << 16),
+                          jnp.where(h == up, t1s, t1)) + 1
+            t = jnp.where(h == 0, 0, t)
+            return (jnp.maximum(best, h), h, h1s, t, t1s,
+                    jnp.where(h > best, t, bt))
         if mode == "affine":
             best, h1, h2s, e1, f1 = st
             h1s = shift(h1)
@@ -115,15 +137,28 @@ def _wave_kernel(sk_ref, out_ref, *carry, mode: str, gap_open: int,
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _emit():
-        out_ref[...] = jnp.max(st[0], axis=1, keepdims=True)
+        best = jnp.max(st[0], axis=1, keepdims=True)
+        out_ref[...] = best
+        if mode == "pid":       # the first lane (query row) at the best
+            first = jnp.min(jnp.where(st[0] == best, lane, lane.shape[1]),
+                            axis=1, keepdims=True)
+            refs[1][...] = jnp.max(jnp.where(lane == first, st[5], 0),
+                                   axis=1, keepdims=True)
+
+
+# XLA names each kernel's op after these (``ungapped_prefilter.1``), so a
+# profile tells the kernels from the skew around them
+_KERNEL_NAMES = {"linear": "wavefront_dp", "affine": "wavefront_dp",
+                 "ungapped": "ungapped_prefilter", "pid": "wavefront_pid"}
 
 
 def _wave_call(sk, *, mode: str, bb: int, interpret: bool | None,
                gap_open: int = 0, gap_extend: int = 0,
                x: int | None = None):
     """Run the shared wave kernel over a skewed (nd, B, Lq) int8 block ->
-    (B, 1) int32. The diagonal axis pads to a ``dc`` multiple with
-    sentinel rows, which no score can come from (`align.gotoh`)."""
+    (B, 1) int32 best scores, and in ``pid`` mode also (B, 1) int32 packed
+    walks. The diagonal axis pads to a ``dc`` multiple with sentinel
+    rows, which no score can come from (`align.gotoh`)."""
     nd, B, Lq = sk.shape
     assert B % bb == 0, "pad the pair block to a bb multiple"
     if x is not None:       # past 11 * L no run can drop; keep it int32
@@ -134,34 +169,45 @@ def _wave_call(sk, *, mode: str, bb: int, interpret: bool | None,
         with jax.named_scope("skew"):
             sk = jnp.concatenate(
                 [sk, jnp.full((pad, B, Lq), SENT8, jnp.int8)], axis=0)
-    n_carry = 5 if mode == "affine" or (mode == "ungapped"
-                                        and x is not None) else 3
+    n_carry = {"affine": 5, "pid": 6}.get(mode, 3 if x is None else 5)
+    out_spec = pl.BlockSpec((bb, 1), lambda i, k: (i, 0))
+    out_shape = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    if mode == "pid":
+        out_spec, out_shape = [out_spec] * 2, [out_shape] * 2
     return pl.pallas_call(
         functools.partial(_wave_kernel, mode=mode, gap_open=gap_open,
                           gap_extend=gap_extend, x=x, dc=dc),
         grid=(B // bb, (nd + pad) // dc),
         in_specs=[pl.BlockSpec((dc, bb, Lq), lambda i, k: (k, i, 0))],
-        out_specs=pl.BlockSpec((bb, 1), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bb, Lq), jnp.int32)] * n_carry,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-        # XLA names the kernel's op after this (``ungapped_prefilter.1``),
-        # so a profile tells the kernel from the skew around it
-        name="ungapped_prefilter" if mode == "ungapped" else "wavefront_dp",
+        name=_KERNEL_NAMES[mode],
     )(sk)
 
 
+# The PID kernel's substitution table: 2 * BLOSUM62 + (residues equal),
+# so one int8 cell carries the score (``>> 1``) and the match bit (``& 1``)
+# through the same skew; PAD stays SENT8 (-50 once shifted, still a
+# sentinel: no path through it can tie a real best)
+_PID_TABLE = np.where(_BSENT == SENT8, SENT8,
+                      2 * _BSENT.astype(np.int32)
+                      + np.eye(ALPHABET_SIZE + 1, dtype=np.int32)
+                      ).astype(np.int8)
+
+
 @jax.named_scope("skew")
-def _skewed(qs, rs):
+def _skewed(qs, rs, table=_BSENT):
     """(B, Lq) x (B, Lr) int8 -> the (nd, B, Lq) int8 skewed substitution
-    block the wave kernels sweep, ``sk[c, b, i] = s_b[i, c-i]`` (SENT8 on
-    PAD and outside the matrix). The reference is skewed by one gather of
-    column ``c - i`` and the query axis resolved by 20 selects. This is
-    bit-identical to `align.gotoh`'s pad-reshape skew, which the TPU
-    compiler takes ~25 s per shape to lay out at L=640 (this form: ~2 s).
-    Its ops sit under the ``skew`` name scope."""
+    block the wave kernels sweep, ``sk[c, b, i] = table[r_b[c-i], q_b[i]]``
+    (SENT8 on PAD and outside the matrix). The reference is skewed by one
+    gather of column ``c - i`` and the query axis resolved by 20 selects.
+    This is bit-identical to `align.gotoh`'s pad-reshape skew, which the
+    TPU compiler takes ~25 s per shape to lay out at L=640 (this form:
+    ~2 s). Its ops sit under the ``skew`` name scope."""
     B, Lq = qs.shape
     Lr = rs.shape[1]
     nd = Lq + Lr - 1
@@ -170,7 +216,7 @@ def _skewed(qs, rs):
     j = jnp.where((j >= 0) & (j < Lr), j, Lr)         # Lr -> the PAD column
     rp = jnp.concatenate([rs, jnp.full((B, 1), PAD, rs.dtype)], axis=1)
     rsk = jnp.transpose(jnp.take(rp, j, axis=1), (1, 0, 2))  # (nd, B, Lq)
-    table = jnp.asarray(_BSENT)
+    table = jnp.asarray(table)
     q = qs.astype(jnp.int32)
     out = jnp.full(rsk.shape, SENT8, jnp.int8)
     for a in range(ALPHABET_SIZE):
@@ -200,6 +246,30 @@ def wave_scores_kernel(qs, rs, *, gap_mode: str = "linear",
         ge = go
     return _wave_call(_skewed(qs, rs), mode=gap_mode, bb=bb,
                       interpret=interpret, gap_open=go, gap_extend=ge)
+
+
+@functools.partial(jax.jit, static_argnames=("bb", "interpret"))
+def wave_pid_kernel(qs, rs, *, bb: int = DEFAULT_BB,
+                    interpret: bool | None = None):
+    """(B, Lq) x (B, Lr) int8 pair block -> (B, 3) int32: the best linear
+    gap local score, and the identities and length of the alignment that
+    `align.smith_waterman._traceback_pid` walks back from the first best
+    cell in row-major order, bit for bit.
+
+    Every cell carries the (identities, length) of the walk that would
+    start there, chosen by the walk's own rule: (0, 0) where H is 0, else
+    its predecessor's plus one step: diagonal if H = H[i-1,j-1] + s, else
+    up if H = H[i-1,j] + gap, else left; a diagonal step adds the match
+    bit. The predecessors lie on the two previous anti-diagonals, so the
+    walk is tracked forward and the DP matrix is never formed. Each lane
+    keeps its first best cell (strict ``>`` as j grows); the first lane at
+    the maximum wins, as ``np.argmax`` picks. B % bb == 0 is handled by
+    padding in ops.wavefront_pid."""
+    # ident << 16 | length stays a positive int32 while length < 2**15
+    assert qs.shape[1] + rs.shape[1] <= 1 << 15, "pair block too long"
+    best, walk = _wave_call(_skewed(qs, rs, _PID_TABLE), mode="pid", bb=bb,
+                            interpret=interpret, gap_open=GAP)
+    return jnp.concatenate([best, walk >> 16, walk & 0xFFFF], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("x", "bb", "interpret"))

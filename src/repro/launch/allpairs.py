@@ -91,9 +91,8 @@ def main(argv=None):
                          "n_shards ownership). Score-only waves (--pallas "
                          "off + --prefilter's ungapped phase, or score "
                          "thresholding) additionally split over that many "
-                         "devices as one SPMD program; the PID traceback "
-                         "wave (the default scoring mode here) is "
-                         "host-bound and stays single-device")
+                         "devices as one SPMD program; PID waves (the "
+                         "default scoring mode here) stay single-device")
     ap.add_argument("--wave-batch", type=int, default=64)
     ap.add_argument("--pallas", action="store_true",
                     help="score waves with the Pallas SW tile kernel "

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.allpairs import WaveConfig
 from repro.kernels.hamming import hamming_dist_kernel
 from repro.kernels.spgemm import upper_pairs_kernel
-from repro.kernels.sw import ungapped_scores_kernel, wave_scores_kernel
+from repro.kernels.sw import (ungapped_scores_kernel, wave_pid_kernel,
+                              wave_scores_kernel)
 
 
 @pytest.fixture(scope="module")
@@ -50,9 +51,10 @@ def one_chip(topo):
 
 def _kernel_op(text: str, name: str) -> bool:
     """True iff the compiled program holds a Pallas kernel whose op XLA
-    named after ``name`` (``%name.1 = ... custom-call(...)``), the name
-    the benchmark's profile readers look for."""
-    return re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(.*"
+    named after ``name`` (``%name.1 = ... custom-call(...)``, its result
+    one array or a tuple), the name the benchmark's profile readers look
+    for."""
+    return re.search(rf"%{name}(\.\d+)? = (\(.*?\)|\S+) custom-call\(.*"
                      r"custom_call_target=\"tpu_custom_call\"", text) \
         is not None
 
@@ -62,10 +64,10 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _wave_batch(batch: int, L: int) -> int:
-    """The pair batch ``allpairs.tiles`` sends at (L, L) under the default
-    cell budget, padded to the kernel's 8-pair block."""
-    b = max(1, min(batch, WaveConfig().max_wave_cells // (L * L)))
+def _wave_batch(batch: int, L: int, Lr: int | None = None) -> int:
+    """The pair batch ``allpairs.tiles`` sends at (L, Lr or L) under the
+    default cell budget, padded to the kernel's 8-pair block."""
+    b = max(1, min(batch, WaveConfig().max_wave_cells // (L * (Lr or L))))
     return -(-b // 8) * 8
 
 
@@ -79,6 +81,19 @@ def test_wave_scores_kernel_compiles(one_chip, gap_mode, L):
         one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
     assert "tpu_custom_call" in text
     assert _kernel_op(text, "wavefront_dp")
+
+
+# PID waves of the NC_000913 corpus (lengths N(316, 80), padded to 64):
+# the fullest bucket, a ragged one, and past its longest protein
+@pytest.mark.parametrize("Lq,Lr", [(320, 320), (256, 704), (640, 640),
+                                   (1024, 1024)])
+def test_wave_pid_kernel_compiles(one_chip, Lq, Lr):
+    B = _wave_batch(WaveConfig().wave_batch, Lq, Lr)
+    text = _compiled_text(
+        lambda q, r: wave_pid_kernel(q, r, interpret=False),
+        one_chip, ((B, Lq), jnp.int8), ((B, Lr), jnp.int8))
+    assert "tpu_custom_call" in text
+    assert _kernel_op(text, "wavefront_pid")
 
 
 @pytest.mark.parametrize("L,x", [(128, None), (384, None), (384, 20),
