@@ -7,12 +7,15 @@ the longest wave the ``max_wave_cells`` plan sends, the all-pairs corpus's
 emission slabs, and a dense top-k sweep over a Swiss-Prot-sized index.
 Interpret-mode tests cannot see what this catches: a dynamic slice Mosaic
 cannot lower, a block that breaks the (8, 128) tiling rule, a kernel past
-the scoped VMEM limit. Nothing runs, so results are the other tests' job.
+the scoped VMEM limit. The wave programs must also hold no skewed
+(diagonal, pair, query) substitution block: the kernels make those rows
+in VMEM. Nothing runs, so results are the other tests' job.
 """
 from __future__ import annotations
 
 import os
 import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.allpairs import WaveConfig
 from repro.kernels.hamming import hamming_dist_kernel
 from repro.kernels.spgemm import upper_pairs_kernel
-from repro.kernels.sw import (ungapped_scores_kernel, wave_pid_kernel,
-                              wave_scores_kernel)
+from repro.kernels.sw import (DIAG_BLOCK, ungapped_scores_kernel,
+                              wave_pid_kernel, wave_scores_kernel)
 
 
 @pytest.fixture(scope="module")
@@ -59,6 +62,24 @@ def _kernel_op(text: str, name: str) -> bool:
         is not None
 
 
+def _skewed_arrays(text: str, B: int, Lq: int, Lr: int) -> list[str]:
+    """The arrays of a compiled wave program that hold a skewed
+    substitution block: their dims hold B, and the diagonal count (nd, or
+    nd padded to a ``DIAG_BLOCK`` multiple) with Lq or flattened with it,
+    in any layout, as XLA's gather, transposes and pad of ``(nd, B, Lq)``
+    rows make them (``s8[639,320,88]``, ``s8[204480,88]`` at
+    Lq = Lr = 320)."""
+    nd = Lq + Lr - 1
+    found = []
+    for m in re.finditer(r"\b[a-z]+\d*\[(\d+(?:,\d+)+)\]", text):
+        dims = Counter(int(d) for d in m.group(1).split(","))
+        for d in {nd, -(-nd // DIAG_BLOCK) * DIAG_BLOCK}:
+            if any(dims >= Counter(want) for want in ([d, B, Lq],
+                                                      [d * Lq, B])):
+                found.append(m.group(0))
+    return found
+
+
 def _compiled_text(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile().as_text()
@@ -81,6 +102,7 @@ def test_wave_scores_kernel_compiles(one_chip, gap_mode, L):
         one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
     assert "tpu_custom_call" in text
     assert _kernel_op(text, "wavefront_dp")
+    assert not _skewed_arrays(text, B, L, L)
 
 
 # PID waves of the NC_000913 corpus (lengths N(316, 80), padded to 64):
@@ -94,6 +116,7 @@ def test_wave_pid_kernel_compiles(one_chip, Lq, Lr):
         one_chip, ((B, Lq), jnp.int8), ((B, Lr), jnp.int8))
     assert "tpu_custom_call" in text
     assert _kernel_op(text, "wavefront_pid")
+    assert not _skewed_arrays(text, B, Lq, Lr)
 
 
 @pytest.mark.parametrize("L,x", [(128, None), (384, None), (384, 20),
@@ -105,6 +128,7 @@ def test_ungapped_scores_kernel_compiles(one_chip, L, x):
         one_chip, ((B, L), jnp.int8), ((B, L), jnp.int8))
     assert "tpu_custom_call" in text
     assert _kernel_op(text, "ungapped_prefilter")
+    assert not _skewed_arrays(text, B, L, L)
 
 
 # (G bands, U+1 offsets, E entries, cap) of the NC_000913-shaped corpus
