@@ -50,47 +50,14 @@ def compare_chaos(fresh: dict, base: dict) -> list[str]:
 
 def compare_allpairs(fresh: dict, base: dict,
                      threshold: float = 0.20) -> list[str]:
-    """All-pairs artifacts: score-phase throughput (device + per-DP-kernel
-    pairs/s) and emission-phase candidate throughput (per join_impl) down
-    more than the threshold are flagged; so are the wavefront and SpGEMM
-    emission speedups slipping under their 2x acceptance floors."""
-    warnings = []
-    for sect in ("pr2", "device"):
-        fv = (fresh.get(sect) or {}).get("pairs_per_sec", 0.0)
-        bv = (base.get(sect) or {}).get("pairs_per_sec", 0.0)
-        if bv > 0 and fv < (1 - threshold) * bv:
-            warnings.append(
-                f"{sect} score-phase pairs/s regressed "
-                f"{100 * (1 - fv / bv):.0f}%: {fv:.0f} vs baseline {bv:.0f}")
-    fd, bd = fresh.get("dp_kernels") or {}, base.get("dp_kernels") or {}
-    for key in sorted(set(fd) | set(bd)):
-        fv, bv = fd.get(key), bd.get(key)
-        if not (isinstance(fv, dict) and isinstance(bv, dict)):
-            continue
-        fp, bp = fv.get("pairs_per_sec", 0.0), bv.get("pairs_per_sec", 0.0)
-        if bp > 0 and fp < (1 - threshold) * bp:
-            warnings.append(
-                f"dp kernel {key} pairs/s regressed "
-                f"{100 * (1 - fp / bp):.0f}%: {fp:.0f} vs baseline {bp:.0f}")
-    sp = fd.get("speedup_wavefront_vs_rowwave")
-    if sp is not None and sp < 2.0:
-        warnings.append(
-            f"wavefront speedup vs rowwave at {sp:.2f}x — under the 2x "
-            f"acceptance floor")
-    fe, be = fresh.get("emission") or {}, base.get("emission") or {}
-    for impl in ("legacy", "spgemm"):
-        fv = (fe.get(impl) or {}).get("cands_per_sec", 0.0)
-        bv = (be.get(impl) or {}).get("cands_per_sec", 0.0)
-        if bv > 0 and fv < (1 - threshold) * bv:
-            warnings.append(
-                f"emission ({impl}) candidates/s regressed "
-                f"{100 * (1 - fv / bv):.0f}%: {fv:.0f} vs baseline {bv:.0f}")
-    esp = fe.get("speedup_spgemm_vs_legacy")
-    if esp is not None and esp < 2.0:
-        warnings.append(
-            f"SpGEMM emission speedup vs legacy at {esp:.2f}x — under the "
-            f"2x acceptance floor")
-    return warnings
+    """All-pairs artifacts: the prefiltered pipeline's score-phase
+    throughput down more than the threshold is flagged."""
+    fv = (fresh.get("device") or {}).get("pairs_per_sec", 0.0)
+    bv = (base.get("device") or {}).get("pairs_per_sec", 0.0)
+    if bv > 0 and fv < (1 - threshold) * bv:
+        return [f"device score-phase pairs/s regressed "
+                f"{100 * (1 - fv / bv):.0f}%: {fv:.0f} vs baseline {bv:.0f}"]
+    return []
 
 
 def compare(fresh: dict, base: dict, threshold: float = 0.20) -> list[str]:
@@ -149,11 +116,8 @@ def main(argv=None) -> int:
               f"({len(fresh.get('fault_counters', {}))} counters)")
     elif not warnings and allpairs:
         dv = (fresh.get("device") or {}).get("pairs_per_sec", 0.0)
-        sp = (fresh.get("dp_kernels") or {}).get(
-            "speedup_wavefront_vs_rowwave")
         print(f"bench_delta: allpairs throughput within "
-              f"{args.threshold:.0%} of baseline (device {dv:.0f} pairs/s"
-              + (f", wavefront {sp:.2f}x rowwave" if sp else "") + ")")
+              f"{args.threshold:.0%} of baseline (device {dv:.0f} pairs/s)")
     elif not warnings:
         fk, bk = fresh["knee"], base["knee"]
         print(f"bench_delta: knee within {args.threshold:.0%} of baseline "

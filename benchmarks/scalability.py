@@ -23,7 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels.ref import hamming_dist_ref
 
 
 _SHARD_PROBE = textwrap.dedent("""
@@ -83,7 +83,7 @@ def run(csv=print):
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.integers(0, 2**32, (1024, 2), dtype=np.uint32))
     r = jnp.asarray(rng.integers(0, 2**32, (4096, 2), dtype=np.uint32))
-    f = jax.jit(lambda a, b: ops.all_pairs_hamming(a, b, prefer_ref=True))
+    f = jax.jit(hamming_dist_ref)
     f(q, r).block_until_ready()
     t0 = time.time()
     for _ in range(5):

@@ -104,7 +104,7 @@ def _run(args):
     # full-SW waves (no prefilter): the DP-bound phase whose scaling the
     # split exists for — the prefiltered pipeline is benchmarked in
     # benchmarks/allpairs.py and its ungapped scans split the same way
-    wave = WaveConfig(wave_batch=64, device_gather=True, inflight=2)
+    wave = WaveConfig(wave_batch=64, inflight=2)
     wave1 = dataclasses.replace(wave, n_devices=1)
     waveS = dataclasses.replace(wave, n_devices=S)
     t_score1, s1 = timed(lambda: score_pairs(ids, lens, join1.pairs, wave1))

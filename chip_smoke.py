@@ -139,7 +139,7 @@ def allpairs_phase(seed: int, n: int = ECOLI_N, n_sample: int = N_SAMPLE):
     from repro.configs.scallops import perf_config
     from repro.index import SignatureIndex
     from repro.kernels import ops
-    from repro.kernels.sw import on_tpu, resolve_interpret
+    from repro.kernels.sw import on_tpu
     from repro.util import next_pow2
 
     clock = Clock("allpairs")
@@ -151,11 +151,8 @@ def allpairs_phase(seed: int, n: int = ECOLI_N, n_sample: int = N_SAMPLE):
     lsh = perf_config()
     cfg = AllPairsConfig(lsh=lsh, min_score=150,
                          wave=WaveConfig(with_pid=False, prefilter=True))
-    pallas = cfg.wave.use_pallas if cfg.wave.use_pallas is not None \
-        else on_tpu()
     print(f"[allpairs] route: DP waves + prefilter -> "
-          f"{'pallas' if pallas else 'jnp'} (interpret="
-          f"{resolve_interpret(cfg.wave.pallas_interpret)})", flush=True)
+          f"{'pallas' if on_tpu() else 'jnp'}", flush=True)
     clock.lap("corpus")
 
     results = {}

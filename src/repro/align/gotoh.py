@@ -25,8 +25,7 @@ each further one — ``open == extend`` degenerates bit-exactly to the
 linear-gap recurrence (H[i, j-1] >= E[i, j-1] at every cell, so the E/F
 lanes never beat the direct 3-way max).
 
-Three CPU-focused tricks make this beat the row wave (~2.8x measured at
-B=64, L=192; see benchmarks/allpairs.py):
+Three CPU-focused tricks make this beat the row wave on XLA:CPU:
 
 * **Sentinel-baked int8 table.** The substitution table is int8 with the
   PAD row/col overwritten by ``SENT8`` (-100), so PAD masking costs no
@@ -47,8 +46,8 @@ B=64, L=192; see benchmarks/allpairs.py):
   optimum: k>=3 crosses an XLA:CPU fusion cliff and regresses 3-6x, as
   does `scan(unroll>1)`.)
 
-All entries return device arrays without a host sync, matching the
-`sw_scores_device` contract the all-pairs scheduler relies on.
+All entries return device arrays without a host sync, the contract the
+all-pairs scheduler's drain ring relies on.
 """
 from __future__ import annotations
 
@@ -210,7 +209,7 @@ def _wave_affine(qs, rs, *, gap_open: int, gap_extend: int):
 def sw_wave_linear(qs, rs, *, gap: int = GAP) -> jax.Array:
     """Batched linear-gap SW scores via the wavefront sweep: (B, Lq) x
     (B, Lr) int8 (PAD-padded) -> (B,) int32 on device. Scores bit-exact
-    with the row wave (`align.smith_waterman.sw_align_batch`)."""
+    with the per-pair row wave (`align.smith_waterman.sw_score`)."""
     return _wave_linear(jnp.asarray(qs), jnp.asarray(rs), gap=gap)
 
 

@@ -24,10 +24,10 @@ The O(L) traceback that extracts matched positions runs host-side in numpy
 Linear gap penalty (the paper's quality analysis uses ungapped/simple-gap
 BLAST alignments; gap open == extend keeps the DP a 3-way max).
 
-The anti-diagonal *wavefront* sweep (:mod:`repro.align.gotoh`) has since
-superseded this row wave as the default score-only kernel
-(``dp_kernel="wavefront"``, ~2.8x on CPU, affine gaps supported); the row
-wave remains the ``"rowwave"`` fallback and the PID/matrix path.
+The anti-diagonal *wavefront* sweep (:mod:`repro.align.gotoh`) is the
+batched score-only DP (affine gaps supported); the row wave remains the
+per-pair definition (:func:`sw_score`) and the DP-matrix path the PID
+traceback reads.
 """
 from __future__ import annotations
 
@@ -74,12 +74,8 @@ def _sw_dp(q, r, return_matrix: bool = False):
     Returns (best_score, H) where H is the (Lq+1, Lr+1) DP matrix if
     requested (int32), else a dummy scalar.
 
-    Both paths are plain int32 scans. The row wave is the *fallback* DP
-    (``dp_kernel="rowwave"``); the int16-carry + unrolled-scan variant it
-    once had is retired — the anti-diagonal wavefront (`repro.align.gotoh`)
-    replaced it as the fast path and the narrowing bought nothing on top
-    of the int32 row wave worth its guard plumbing (1.13x, vs 2.8x for
-    the wavefront; see ROADMAP "Perf ledger").
+    Both paths are plain int32 scans: the definition batched waves are
+    tested against, not a fast path.
     """
     if return_matrix:
         sub = _sub_matrix(q, r)
@@ -109,19 +105,6 @@ def sw_score(q, r) -> int:
     return int(s)
 
 
-@jax.jit
-def _sw_scores_batch(qs, rs):
-    return jax.vmap(lambda a, b: _sw_dp(a, b)[0])(qs, rs)
-
-
-def sw_scores_device(qs, rs) -> jax.Array:
-    """Device-resident wave entry: (B, Lq) x (B, Lr) int8 device (or host)
-    arrays -> (B,) int32 best scores, returned *on device* without a host
-    sync — the all-pairs scheduler chains this behind its fused gather and
-    drains results through an async ring (`repro.allpairs.tiles`)."""
-    return _sw_scores_batch(qs, rs)
-
-
 # ------------------------------------------------------------ device gather
 def gather_rows(ids_dev, lens_dev, idx, L: int):
     """Fused wave gather: (N, Lmax) device corpus -> (B, L) PAD-masked block
@@ -137,55 +120,41 @@ def gather_rows(ids_dev, lens_dev, idx, L: int):
     return jnp.where(pos < ln[:, None], rows, PAD)
 
 
-def dp_scores_block(qm, rm, *, dp_kernel: str = "wavefront",
-                    gap_mode: str = "linear", gap_open: int | None = None,
+def dp_scores_block(qm, rm, *, gap_mode: str = "linear",
+                    gap_open: int | None = None,
                     gap_extend: int | None = None) -> jax.Array:
-    """Route a gathered (B, Lq) x (B, Lr) pair block to a DP sweep.
-
-    ``dp_kernel`` picks the sweep order — ``"wavefront"`` (anti-diagonal,
-    `repro.align.gotoh`, the fast default) or ``"rowwave"`` (the int32
-    row-wave fallback, linear-gap only). ``gap_mode`` picks the penalty
-    model — ``"linear"`` (scores identical under both kernels) or
-    ``"affine"`` (Gotoh; wavefront-only). Traceable: safe to call under an
-    enclosing jit with the knobs static.
+    """Score a gathered (B, Lq) x (B, Lr) pair block with the wavefront
+    sweep (`repro.align.gotoh`) under ``gap_mode`` — ``"linear"`` (gap
+    ``gap_open``, default GAP) or ``"affine"`` (Gotoh, defaults -11/-1).
+    Traceable: safe to call under an enclosing jit with the knobs static.
     """
-    if gap_mode not in ("linear", "affine"):
-        raise ValueError(f"unknown gap_mode {gap_mode!r}")
-    if dp_kernel not in ("wavefront", "rowwave"):
-        raise ValueError(f"unknown dp_kernel {dp_kernel!r}")
     from .gotoh import GAP_EXTEND, GAP_OPEN, _wave_affine_impl, \
         _wave_linear_impl
     if gap_mode == "affine":
-        if dp_kernel == "rowwave":
-            raise ValueError("affine gaps need dp_kernel='wavefront' "
-                             "(the row wave's prefix-scan closed form "
-                             "only holds for linear penalties)")
         return _wave_affine_impl(
             qm, rm, GAP_OPEN if gap_open is None else gap_open,
             GAP_EXTEND if gap_extend is None else gap_extend)
-    if dp_kernel == "rowwave":
-        return _sw_scores_batch(qm, rm)
+    if gap_mode != "linear":
+        raise ValueError(f"unknown gap_mode {gap_mode!r}")
     return _wave_linear_impl(qm, rm, GAP if gap_open is None else gap_open)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "Lq", "Lr", "dp_kernel", "gap_mode", "gap_open", "gap_extend"))
+    "Lq", "Lr", "gap_mode", "gap_open", "gap_extend"))
 @trace_sentinel("sw_gather")
 def sw_gather_scores(q_ids, q_lens, r_ids, r_lens, qi, ri, *,
-                     Lq: int, Lr: int, dp_kernel: str = "wavefront",
-                     gap_mode: str = "linear", gap_open: int | None = None,
+                     Lq: int, Lr: int, gap_mode: str = "linear",
+                     gap_open: int | None = None,
                      gap_extend: int | None = None) -> jax.Array:
     """ONE jitted program: gather both pair sides from device-resident
     corpora and run the full SW wave. (qi, ri) (B,) int32 with -1 padding;
     padding slots score 0. Used by the all-pairs scheduler (q_ids is r_ids)
-    and the serving re-rank (queries vs the reference store). DP routing
-    knobs are static (see :func:`dp_scores_block`); defaults — wavefront
-    sweep, linear gaps — keep scores bit-exact with the historical
-    row-wave path."""
+    and the serving re-rank (queries vs the reference store). The gap
+    knobs are static (see :func:`dp_scores_block`)."""
     qm = gather_rows(q_ids, q_lens, qi, Lq)
     rm = gather_rows(r_ids, r_lens, ri, Lr)
-    return dp_scores_block(qm, rm, dp_kernel=dp_kernel, gap_mode=gap_mode,
-                           gap_open=gap_open, gap_extend=gap_extend)
+    return dp_scores_block(qm, rm, gap_mode=gap_mode, gap_open=gap_open,
+                           gap_extend=gap_extend)
 
 
 # ------------------------------------------------------------ ungapped X-drop
@@ -279,8 +248,10 @@ def _sw_batch_with_matrix(qs, rs):
 
 
 def sw_align_batch(qs, rs) -> np.ndarray:
-    """Batched best-scores: (N, Lq) x (N, Lr) -> (N,) int32 (one jit call)."""
-    return np.asarray(_sw_scores_batch(jnp.asarray(qs), jnp.asarray(rs)))
+    """Batched best-scores: (N, Lq) x (N, Lr) -> (N,) int32 (one jit call
+    of the linear-gap wavefront sweep; scores equal :func:`sw_score`)."""
+    from .gotoh import sw_wave_linear
+    return np.asarray(sw_wave_linear(qs, rs))
 
 
 def _traceback_pid(H: np.ndarray, q: np.ndarray, r: np.ndarray,

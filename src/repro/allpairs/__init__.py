@@ -8,17 +8,18 @@ index:
 
   corpus -> SignatureIndex.build -> LSH self-join (within-bucket pairs,
   deduped, upper-triangular CSR) -> pair scheduler (fixed-shape waves keyed
-  by padded length) -> batched Smith-Waterman row-wave scoring (+ PID)
+  by padded length) -> batched Smith-Waterman wavefront scoring (+ PID)
   -> similarity graph -> union-find connected components = protein families
 
 * ``selfjoin`` — :func:`lsh_self_join`: exact band-collision enumeration
-  with the grow-and-retry capacity discipline; CSR adjacency output.
+  as a masked SpGEMM, sized at the exact emission total; CSR adjacency
+  output.
 * ``tiles``   — :func:`score_pairs`: waves keyed by padded-length shape
   alone, *device-resident* batched SW waves — fused on-device gathers
   (corpus uploaded once, per-wave H2D is just pair indices), an optional
   ungapped X-drop prefilter that skips full DP for hopeless pairs, and
   async double-buffered dispatch drained through a small in-flight ring
-  (jnp row-wave or the Pallas tile kernel).
+  (the Pallas kernels on TPU, the jnp wavefront sweeps elsewhere).
 * ``graph``   — :func:`cluster_families`: PID/score-thresholded edges,
   union-find components, families largest-first.
 
@@ -67,10 +68,6 @@ class AllPairsConfig:
                                   # the threshold) — the surviving pair set
                                   # is bit-exact with the unfused wave
                                   # prefilter, which is then skipped
-    join_impl: str = "spgemm"    # candidate-generation orchestration:
-                                 # "spgemm" (fused device-resident masked
-                                 # A^T A) or "legacy" (pre-SpGEMM host-merge
-                                 # path, kept one PR) — identical pair arrays
 
 
 @dataclass(frozen=True)
@@ -120,13 +117,13 @@ def all_pairs_search(ids, lens, cfg: AllPairsConfig | None = None,
     pf, wave = _join_prefilter(cfg, ids, lens)
     join = lsh_self_join(index, d=cfg.lsh.d if cfg.hamming_filter else None,
                          max_pairs=cfg.max_pairs, n_shards=cfg.n_shards,
-                         prefilter=pf, join_impl=cfg.join_impl)
+                         prefilter=pf)
     scored = score_pairs(ids, lens, join.pairs, wave)
     with span("graph", cat="allpairs", pairs=len(join.pairs)):
         if cfg.wave.with_pid:
             families = cluster_families(index.size, join.pairs, scored.pid,
                                         min_pid=cfg.min_pid)
-        else:       # score-only waves (e.g. the Pallas kernel path)
+        else:       # score-only waves
             families = cluster_families(index.size, join.pairs, None,
                                         scores=scored.scores,
                                         min_score=cfg.min_score)
@@ -199,7 +196,7 @@ def all_pairs_ingest(ids, lens, base_size: int,
     join = lsh_delta_join(index, base_size=base_size,
                           d=cfg.lsh.d if cfg.hamming_filter else None,
                           max_pairs=cfg.max_pairs, n_shards=cfg.n_shards,
-                          prefilter=pf, join_impl=cfg.join_impl)
+                          prefilter=pf)
     scored = score_pairs(ids, lens, join.pairs, wave)
     mask = _edge_mask(scored, cfg, join.pairs)
     forest.grow(index.size)

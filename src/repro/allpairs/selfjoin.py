@@ -15,19 +15,12 @@ Candidate emission is the masked SpGEMM primitive of
 :mod:`repro.index.spgemm` — each bucket slab is the CSR of a
 sequence×bucket incidence matrix ``A``, the self-join is the strict upper
 triangle of ``AᵀA``, and the delta join is the ``Aᵀ_delta · A_resident``
-cross mask (resident×resident never forms). Two orchestrations share those
-products behind ``join_impl=``:
-
-* ``"spgemm"`` (default) — the fused path: per-band products, cross-band
-  dedup, the optional exact Hamming filter, and survivor compaction run
-  device-resident (one program when per-shard demand is uniform), the
-  output capacity is sized at the exact emission total so the dedup can
-  never overflow (no grow-and-retry), and the fused prefilter consumes
-  the pair buffer in place — the join pays ONE host sync (the count).
-* ``"legacy"`` — the pre-SpGEMM orchestration (emission programs → host
-  merge → separate dedup under grow-and-retry), kept for one PR as the
-  bit-exactness reference; both paths produce IDENTICAL result arrays
-  (the dedup output is the sorted unique pair set either way).
+cross mask (resident×resident never forms). Per-band products, cross-band
+dedup, the optional exact Hamming filter, and survivor compaction run
+device-resident (one program when per-shard demand is uniform); the output
+capacity is sized at the exact emission total so the dedup can never
+overflow (no grow-and-retry), and the fused prefilter consumes the pair
+buffer in place — the join pays ONE host sync (the count).
 
 Emission runs over the shard-owned bucket slabs of
 :class:`~repro.index.partition.BucketPartition` (``mix32(key) % n_shards``
@@ -57,9 +50,8 @@ the grown corpus (any collision either has both rows resident, or its
 later row lives in a new segment).
 
 Emission reuses the fixed-capacity buffer discipline of ``core/join.py``
-(rows past the count are -1; ``overflowed`` means rows were truncated), and
-the legacy orchestration wraps dedup in the same grow-and-retry loop as the
-serving layer — no silent caps.
+(rows past the count are -1); demand past ``max_grow`` raises — no silent
+caps.
 """
 from __future__ import annotations
 
@@ -81,16 +73,6 @@ from ..index.spgemm import (spgemm_cross_slab, spgemm_join_self,
 from ..index.store import SignatureIndex
 from ..obs import span, trace_sentinel
 from ..util import next_pow2
-
-JOIN_IMPLS = ("spgemm", "legacy")
-
-
-def _check_impl(join_impl: str) -> str:
-    if join_impl not in JOIN_IMPLS:
-        raise ValueError(f"unknown join_impl {join_impl!r} "
-                         f"(expected one of {JOIN_IMPLS})")
-    return join_impl
-
 
 @functools.lru_cache(maxsize=16)
 def _default_mesh(n: int, axis_name: str):
@@ -154,12 +136,11 @@ def _shard_caps(part: BucketPartition) -> np.ndarray:
 
 
 def _emit_partition(part: BucketPartition, caps: np.ndarray, mesh,
-                    axis_name: str, *, to_host: bool = True):
+                    axis_name: str):
     """Emit every shard's within-bucket pairs over the partition slabs;
-    returns the merged (M, 2) candidate rows (-1 rows allowed — the
-    downstream dedup drops them) — numpy when ``to_host`` (the legacy
-    orchestration), a device array otherwise (the spgemm pack consumes it
-    without a host round-trip).
+    returns the merged (M, 2) candidate rows on device (-1 rows allowed —
+    the downstream dedup drops them), which the pack consumes without a
+    host round-trip.
 
     Uniform demand (all nonzero shard caps equal): ONE program — the
     ``shard_map`` SPMD emission on a mesh of ``part.n_shards`` devices, or
@@ -186,8 +167,7 @@ def _emit_partition(part: BucketPartition, caps: np.ndarray, mesh,
             out = spgemm_self_slab(offs_s.reshape(-1, offs_s.shape[-1]),
                                    ids_s.reshape(-1, ids_s.shape[-1]),
                                    cap=cap)
-        return np.asarray(out).reshape(-1, 2) if to_host \
-            else out.reshape(-1, 2)
+        return out.reshape(-1, 2)
     _, offs_np, ids_np = part.host_slabs()
     devices = list(mesh.devices.flat) if mesh is not None else None
     bufs = []
@@ -201,9 +181,6 @@ def _emit_partition(part: BucketPartition, caps: np.ndarray, mesh,
         bufs.append(spgemm_self_slab(offs, ids, cap=int(caps[s])))
     # ragged merge: per-shard buffers differ in cap, so the merge is a
     # concat (the cross-shard dedup downstream lexsorts anyway)
-    if to_host:
-        return np.concatenate(
-            [np.asarray(b).reshape(-1, 2) for b in bufs], axis=0)
     if devices is not None:
         # the pack runs as ONE program: gather the per-shard buffers onto
         # the lead device (device-to-device, still no host round-trip)
@@ -341,38 +318,17 @@ def _finish_pairs(pairs_dev, n_cand: int, index: SignatureIndex,
                          n_prefiltered=n_cand - len(kept))
 
 
-def _dedup_and_pack(cand, index: SignatureIndex,
-                    d: int | None, cap: int, max_grow: int, scope: str,
-                    prefilter: JoinPrefilter | None = None
-                    ) -> SelfJoinResult:
-    """Legacy-orchestration tail: cross-band/-shard dedup + optional exact
-    Hamming filter under the grow-and-retry capacity discipline (the
-    spgemm path sizes the output at the exact emission total instead and
-    never retries)."""
-    while True:
-        pairs, count = spgemm_pack(cand, index.device_sigs,
-                                   out_cap=cap, d=d)
-        if int(count) <= cap:
-            return _finish_pairs(pairs, int(count), index, prefilter)
-        if cap >= max_grow:         # dedup union overran the buffer
-            _grow_overflow(scope, max_grow)
-        cap = min(cap * 2, max_grow)    # grow-and-retry
-
-
 def _pack_exact(cand_dev, index: SignatureIndex, d: int | None,
                 total: int, max_grow: int, scope: str,
                 prefilter: JoinPrefilter | None,
-                limit: int | None = None) -> SelfJoinResult:
-    """SpGEMM-orchestration tail: the pack output is sized at the exact
-    emission total (survivors <= emitted always), so it can never
-    overflow — no grow-and-retry, one host sync (the count).
+                limit: int) -> SelfJoinResult:
+    """Join tail: the pack output is sized at the exact emission total
+    (survivors <= emitted always), so it can never overflow — no
+    grow-and-retry, one host sync (the count).
 
-    ``limit`` is the legacy-equivalent capacity ceiling
-    (``max(starting cap, max_grow)``): legacy only raises when the dedup
-    union must GROW past ``max_grow``, so a count the starting buffer
-    already covers must succeed here too — never raise where legacy
-    would not."""
-    limit = max_grow if limit is None else limit
+    ``limit`` is the capacity ceiling ``max(starting cap, max_grow)``: a
+    deduplicated count past it raises, and a count the starting buffer
+    (``max_pairs``) already covers never does."""
     out_cap = next_pow2(max(1, min(total, limit)))
     pairs, count = spgemm_pack(cand_dev, index.device_sigs,
                                out_cap=out_cap, d=d)
@@ -402,8 +358,7 @@ def lsh_self_join(index: SignatureIndex, *, d: int | None = None,
                   max_grow: int = 1 << 24,
                   n_shards: int | None = None,
                   mesh=None, axis_name: str = "data",
-                  prefilter: JoinPrefilter | None = None,
-                  join_impl: str = "spgemm") -> SelfJoinResult:
+                  prefilter: JoinPrefilter | None = None) -> SelfJoinResult:
     """All-pairs candidate generation over the indexed corpus.
 
     Emits every within-bucket pair of every band, deduplicates across bands
@@ -413,13 +368,9 @@ def lsh_self_join(index: SignatureIndex, *, d: int | None = None,
     the process has that many devices, the first ``n_shards`` of
     ``jax.devices()`` — each shard emits its buckets' pairs on its own
     device in parallel; the pair set (and the result arrays) are
-    bit-identical for every ``n_shards``.
-
-    ``join_impl="spgemm"`` (default) fuses emission + dedup + filter +
-    compaction device-resident and sizes the output at the exact emission
-    total (no grow-and-retry, one host sync); ``"legacy"`` is the
-    pre-SpGEMM orchestration (host merge + grow-and-retry), kept one PR as
-    the bit-exactness reference — both produce identical arrays.
+    bit-identical for every ``n_shards``. Emission, dedup, filter and
+    compaction run device-resident with the output sized at the exact
+    emission total (no grow-and-retry, one host sync).
 
     Capacity discipline: per-shard emission capacity is sized from host-side
     int64 bucket totals (the device-side int32 count would wrap for a
@@ -433,7 +384,6 @@ def lsh_self_join(index: SignatureIndex, *, d: int | None = None,
     returned pairs are exactly the survivors (``result.ungapped`` holds
     their prefilter scores, ``result.n_prefiltered`` the rejected count).
     """
-    _check_impl(join_impl)
     n = int(n_shards) if n_shards is not None else index.n_shards
     part = index.partition(n)
     # the overflow check judges TRUE demand (the quantized caps below only
@@ -447,18 +397,13 @@ def lsh_self_join(index: SignatureIndex, *, d: int | None = None,
     caps = _shard_caps(part)
     mesh = _resolve_mesh(n, mesh, axis_name)
     # Emission runs ONCE at per-shard exact-or-2x capacity (it can never
-    # truncate); only the deduplicated cross-shard union can grow (legacy)
-    # — the spgemm pack is sized at the exact emission total instead.
-    with span("emission", cat="allpairs", shards=n, impl=join_impl,
+    # truncate), and the pack is sized at the exact emission total.
+    with span("emission", cat="allpairs", shards=n,
               spmd=mesh is not None, need=need):
-        if join_impl == "legacy":
-            cand = _emit_partition(part, caps, mesh, axis_name)
-            cap = max(max_pairs, int(caps.max()))
-            return _dedup_and_pack(cand, index, d, cap, max_grow,
-                                   "self-join", prefilter=prefilter)
         total = int(part.pair_totals.sum())
-        # legacy-equivalent ceiling: legacy starts at max(max_pairs, caps)
-        # and only raises when the union must GROW past max_grow
+        # capacity ceiling: a count within the starting buffer
+        # max(max_pairs, caps) never raises; past it the deduplicated
+        # union may grow up to max_grow
         limit = max(max_pairs, int(caps.max()), max_grow)
         live = caps[caps > 0]
         uniform = live.size == 0 or int(live.min()) == int(live.max())
@@ -489,7 +434,7 @@ def lsh_self_join(index: SignatureIndex, *, d: int | None = None,
             return _finish_pairs(pairs, n_cand, index, prefilter)
         # SPMD or skewed demand: per-shard products, device-side merge,
         # fused pack — still no host round-trip of candidate rows
-        cand = _emit_partition(part, caps, mesh, axis_name, to_host=False)
+        cand = _emit_partition(part, caps, mesh, axis_name)
         return _pack_exact(cand, index, d, total, max_grow, "self-join",
                            prefilter, limit=limit)
 
@@ -554,8 +499,7 @@ def lsh_delta_join(index: SignatureIndex, *, base_size: int,
                    max_grow: int = 1 << 24,
                    n_shards: int | None = None,
                    mesh=None, axis_name: str = "data",
-                   prefilter: JoinPrefilter | None = None,
-                   join_impl: str = "spgemm") -> SelfJoinResult:
+                   prefilter: JoinPrefilter | None = None) -> SelfJoinResult:
     """Incremental self-join: only the pairs touching rows >= ``base_size``.
 
     ``base_size`` must be a segment boundary (the corpus size before the
@@ -572,10 +516,8 @@ def lsh_delta_join(index: SignatureIndex, *, base_size: int,
     the pre-ingest pair set to EXACTLY the from-scratch
     :func:`lsh_self_join` over the grown corpus (same dedup, same optional
     Hamming filter, same sort order); tests/test_lifecycle.py asserts the
-    equality. ``join_impl="legacy"`` keeps the pre-SpGEMM single-device
-    orchestration for one PR (identical arrays).
+    equality.
     """
-    _check_impl(join_impl)
     index.seal()
     segs = index.segments
     boundaries = [s.base for s in segs] + [index.size]
@@ -586,8 +528,7 @@ def lsh_delta_join(index: SignatureIndex, *, base_size: int,
     if base_size == index.size:     # nothing new
         return _pairs_to_csr(np.zeros((0, 2), np.int32), index.size)
     k = boundaries.index(base_size)
-    n = 1 if join_impl == "legacy" else (
-        int(n_shards) if n_shards is not None else index.n_shards)
+    n = int(n_shards) if n_shards is not None else index.n_shards
     mesh = _resolve_mesh(n, mesh, axis_name)
 
     def part(i) -> BucketPartition:
@@ -622,7 +563,7 @@ def lsh_delta_join(index: SignatureIndex, *, base_size: int,
 
     bufs = []
     total = 0
-    with span("delta_emission", cat="allpairs", shards=n, impl=join_impl,
+    with span("delta_emission", cat="allpairs", shards=n,
               new_segments=len(segs) - k, resident_segments=k):
         for s in range(k, len(segs)):
             within = part(s).pair_totals
@@ -643,13 +584,7 @@ def lsh_delta_join(index: SignatureIndex, *, base_size: int,
                 bufs.append(emit_cross(s, r, next_pow2(need_c)))
         if not bufs:
             return _pairs_to_csr(np.zeros((0, 2), np.int32), index.size)
-        if join_impl == "legacy":
-            # ragged host merge (buffers differ in cap); dedup lexsorts
-            cand = np.concatenate(
-                [np.asarray(b).reshape(-1, 2) for b in bufs], axis=0)
-            return _dedup_and_pack(cand, index, d, max_pairs, max_grow,
-                                   "delta join", prefilter=prefilter)
-        # spgemm: device-side ragged merge + exact-sized fused pack
+        # device-side ragged merge + exact-sized fused pack
         cand = jnp.concatenate([b.reshape(-1, 2) for b in bufs], axis=0)
     return _pack_exact(cand, index, d, total, max_grow, "delta join",
                        prefilter, limit=max(max_pairs, max_grow))

@@ -15,8 +15,7 @@ on device:
 2. **fused device gather** — the padded corpus ``(N, Lmax)`` is uploaded
    ONCE; each wave is one jitted take-and-mask program over pair index
    arrays (``ids[pair_idx, :Lq]``), so the only per-wave H2D traffic is the
-   (B,) index vectors — no per-pair host copy loop
-   (``device_gather=False`` restores the PR 2 host path, bit-exact).
+   (B,) index vectors — no per-pair host copy loop.
 3. **ungapped X-drop prefilter** (``prefilter=True``) — every wave first
    runs a cheap ungapped diagonal scan (BLAST-style X-drop extension, an
    elementwise DP with no within-row prefix scan); only pairs whose
@@ -28,20 +27,22 @@ on device:
    while wave n's scores are still in flight; a small FIFO ring
    (``inflight``) drains ``device_get`` results, so wall-clock tracks
    device DP time instead of Python dispatch.
-5. **multi-device waves** (``n_devices > 1``) — each wave batch is split
-   over the first ``n_devices`` of ``jax.devices()`` as ONE SPMD program
-   (``shard_map``: pair index vectors partitioned, corpus replicated), so
-   ``n_devices`` pair blocks gather+score concurrently per dispatch — the
-   reduce-side join of the sharded self-join run on the reducers
-   themselves. SPMD (not per-device round-robin dispatch) is load-bearing:
+5. **multi-device waves** (``n_devices > 1``, jnp route) — each wave batch
+   is split over the first ``n_devices`` of ``jax.devices()`` as ONE SPMD
+   program (``shard_map``: pair index vectors partitioned, corpus
+   replicated), so ``n_devices`` pair blocks gather+score concurrently per
+   dispatch. SPMD (not per-device round-robin dispatch) is load-bearing:
    the CPU PJRT client serializes independent per-device executions, and
-   only partitions *inside* one program run on parallel threads; on
-   accelerator meshes the same program overlaps the usual way. Pairs are
+   only partitions *inside* one program run on parallel threads. Pairs are
    embarrassingly parallel, so the split is bit-exact by construction.
 
+Each wave is one call of a wave step chosen once per phase (`_wave_step`)
+by kind and backend: on TPU the device gather feeds the Pallas kernels
+(`kernels.ops`); elsewhere the jnp sweeps of `align` run fused behind the
+gather, split over the mesh when ``n_devices > 1``.
+
     pairs ──wave_plan──▶ [gather ▶ prefilter ▶ full SW] ──▶ drain ring
-                           (one jitted program per wave shape,
-                            split P("wave") over n_devices)
+                           (one jitted program per wave shape)
 
 Scores (and optionally PID: on TPU the wavefront kernel's PID mode, which
 tracks each alignment's identities and length through the sweep; off-TPU
@@ -59,9 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..align.smith_waterman import (gather_rows, sw_gather_scores,
-                                    sw_scores_device, sw_wave_pid,
-                                    ungapped_xdrop_scores)
-from ..core.alphabet import PAD
+                                    sw_wave_pid, ungapped_xdrop_scores)
+from ..kernels import ops
 from ..kernels.sw import on_tpu
 from ..obs import span, trace_sentinel
 
@@ -71,31 +71,16 @@ class WaveConfig:
     wave_batch: int = 64         # pairs per full-SW wave (upper bound)
     len_quantum: int = 64        # pad pair lengths to multiples of this
     max_wave_cells: int = 1 << 23  # B*Lq*Lr budget; shrinks B for long pairs
-    device_gather: bool = True   # fused on-device wave gather (False: PR 2
-                                 # host copy loop, bit-exact, for comparison)
     inflight: int = 2            # async ring depth: waves in flight before
                                  # the oldest result is drained to host
-    n_devices: int = 1           # split each wave over this many devices
-                                 # as one SPMD shard_map program (clamped
-                                 # to jax.device_count(); needs
-                                 # device_gather; wave_batch becomes the
-                                 # PER-DEVICE batch). Ignored by the
-                                 # Pallas waves (PID ones included) and
-                                 # the host PID route: both stay
-                                 # single-device.
-    dp_kernel: str = "wavefront"  # score-only DP sweep: "wavefront" (the
-                                 # anti-diagonal Gotoh sweep of
-                                 # `align.gotoh`, ~2.8x on CPU) or
-                                 # "rowwave" (the int32 prefix-scan row
-                                 # wave, linear-gap fallback). PID waves
-                                 # take the wavefront kernel's PID mode
-                                 # with use_pallas, else the row wave
-                                 # (the host traceback reads its DP
-                                 # matrices).
-    gap_mode: str = "linear"     # gap model: "linear" (GAP = -4, both
-                                 # kernels, scores bit-exact across them)
-                                 # or "affine" (Gotoh open/extend,
-                                 # wavefront only)
+    n_devices: int = 1           # split each jnp wave over this many
+                                 # devices as one SPMD shard_map program
+                                 # (clamped to jax.device_count();
+                                 # wave_batch becomes the PER-DEVICE
+                                 # batch). The Pallas waves and the host
+                                 # PID route stay single-device.
+    gap_mode: str = "linear"     # gap model: "linear" (GAP = -4) or
+                                 # "affine" (Gotoh open/extend; score-only)
     gap_open: int | None = None  # None -> GAP (linear) / -11 (affine)
     gap_extend: int | None = None  # None -> -1; affine only
     prefilter: bool = False      # ungapped X-drop prefilter before full SW
@@ -106,12 +91,6 @@ class WaveConfig:
                                  # run-best carry drops out of the scan)
     prefilter_batch: int = 256   # pairs per prefilter wave (the ungapped
                                  # scan is elementwise, so it batches wider)
-    use_pallas: bool | None = None  # route waves (score-only and PID)
-                                 # through the Pallas tile kernels; None =
-                                 # auto (TPU only — interpret mode is
-                                 # slower than the jnp wave off-TPU)
-    pallas_interpret: bool | None = None  # kernel interpret override
-                                 # (None = autodetect by backend)
     with_pid: bool = False       # also compute each pair's PID and
                                  # alignment length (linear gaps only)
     profile: bool = False        # block inside each ``wave`` span, so
@@ -202,16 +181,15 @@ def _sharded_wave_fns(devices: tuple):
     ax = "wave"
 
     @functools.partial(jax.jit, static_argnames=(
-        "Lq", "Lr", "dp_kernel", "gap_mode", "gap_open", "gap_extend"))
+        "Lq", "Lr", "gap_mode", "gap_open", "gap_extend"))
     @trace_sentinel("wave_sw_spmd", static_key=(devices,))
     def sw_fn(ids_dev, lens_dev, pi, pj, *, Lq: int, Lr: int,
-              dp_kernel: str = "wavefront", gap_mode: str = "linear",
-              gap_open: int | None = None, gap_extend: int | None = None):
+              gap_mode: str = "linear", gap_open: int | None = None,
+              gap_extend: int | None = None):
         f = jax.shard_map(
             lambda i, l, a, b: sw_gather_scores(
-                i, l, i, l, a, b, Lq=Lq, Lr=Lr, dp_kernel=dp_kernel,
-                gap_mode=gap_mode, gap_open=gap_open,
-                gap_extend=gap_extend),
+                i, l, i, l, a, b, Lq=Lq, Lr=Lr, gap_mode=gap_mode,
+                gap_open=gap_open, gap_extend=gap_extend),
             mesh=mesh, in_specs=(P(), P(), P(ax), P(ax)), out_specs=P(ax),
             check_vma=False)
         return f(ids_dev, lens_dev, pi, pj)
@@ -265,17 +243,6 @@ class _WaveStats:
         self.shapes: set = set()
 
 
-def _host_gather(ids, lens, pairs, chunk, B, Lq, Lr):
-    """PR 2 path: assemble the wave with a per-pair host copy loop."""
-    qm = np.full((B, Lq), PAD, np.int8)
-    rm = np.full((B, Lr), PAD, np.int8)
-    for n, p in enumerate(chunk):
-        i, j = pairs[p]
-        qm[n, :lens[i]] = ids[i, :lens[i]]
-        rm[n, :lens[j]] = ids[j, :lens[j]]
-    return qm, rm
-
-
 def _pad_chunk(pairs, chunk, B):
     """Pair index vectors for one wave, -1-padded to the fixed batch B."""
     pi = np.full(B, -1, np.int32)
@@ -283,38 +250,6 @@ def _pad_chunk(pairs, chunk, B):
     pi[:len(chunk)] = pairs[chunk, 0]
     pj[:len(chunk)] = pairs[chunk, 1]
     return pi, pj
-
-
-def _score_block(qm, rm, kind: str, x: int | None, use_pallas: bool,
-                 cfg: WaveConfig):
-    """Score one assembled (B, Lq) x (B, Lr) block on device, routed by
-    ``cfg.dp_kernel`` / ``cfg.gap_mode`` (see WaveConfig). ``kind="pid"``
-    takes the Pallas PID kernel: (B, 3) score, identities, length."""
-    if use_pallas:
-        from ..kernels import ops
-        if kind == "pid":
-            return ops.wavefront_pid(qm, rm, interpret=cfg.pallas_interpret)
-        if kind == "ungapped":
-            return ops.ungapped_wave_scores(qm, rm, x=x,
-                                            interpret=cfg.pallas_interpret)
-        if cfg.dp_kernel == "wavefront":
-            return ops.wavefront_scores(
-                qm, rm, gap_mode=cfg.gap_mode, gap_open=cfg.gap_open,
-                gap_extend=cfg.gap_extend, interpret=cfg.pallas_interpret)
-        return ops.sw_wave_scores(qm, rm, interpret=cfg.pallas_interpret)
-    if kind == "ungapped":
-        return ungapped_xdrop_scores(qm, rm, x=x)
-    if cfg.dp_kernel == "wavefront":
-        from ..align.gotoh import sw_wave_affine, sw_wave_linear
-        if cfg.gap_mode == "affine":
-            kw = {} if cfg.gap_open is None else {"gap_open": cfg.gap_open}
-            if cfg.gap_extend is not None:
-                kw["gap_extend"] = cfg.gap_extend
-            return sw_wave_affine(qm, rm, **kw)
-        if cfg.gap_open is None:
-            return sw_wave_linear(qm, rm)
-        return sw_wave_linear(qm, rm, gap=cfg.gap_open)
-    return sw_scores_device(jnp.asarray(qm), jnp.asarray(rm))
 
 
 def _iter_wave_chunks(sub, lens, cfg: WaveConfig, wave_batch: int,
@@ -339,58 +274,60 @@ def _store(out):
     return sink
 
 
-def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, sink,
+def _wave_step(kind: str, cfg: WaveConfig, dev, pallas: bool, ndev: int):
+    """The one device call of a wave of ``kind`` ("ungapped" | "sw" |
+    "pid"): ``wave(pi, pj, Lq, Lr)`` over the (B,) -1-padded pair index
+    vectors, returning the wave's results on device. ``pallas``: the
+    device gather, then the Pallas kernel (``pid`` exists only here);
+    else the jnp gather+sweep program, split over the first ``ndev``
+    devices when ``ndev > 1``."""
+    ids_dev, lens_dev = dev
+    if pallas:
+        score = {"pid": ops.wavefront_pid,
+                 "ungapped": functools.partial(ops.ungapped_wave_scores,
+                                               x=cfg.xdrop),
+                 "sw": functools.partial(
+                     ops.wavefront_scores, gap_mode=cfg.gap_mode,
+                     gap_open=cfg.gap_open, gap_extend=cfg.gap_extend)}[kind]
+
+        def wave(pi, pj, Lq, Lr):
+            qm, rm = _gather_wave(ids_dev, lens_dev, jnp.asarray(pi),
+                                  jnp.asarray(pj), Lq=Lq, Lr=Lr)
+            return score(qm, rm)
+        return wave
+    gaps = dict(gap_mode=cfg.gap_mode, gap_open=cfg.gap_open,
+                gap_extend=cfg.gap_extend)
+    if ndev > 1:
+        sw_fn, ungapped_fn = _sharded_wave_fns(tuple(jax.devices()[:ndev]))
+        if kind == "ungapped":
+            return lambda pi, pj, Lq, Lr: ungapped_fn(
+                ids_dev, lens_dev, pi, pj, x=cfg.xdrop, Lq=Lq, Lr=Lr)
+        return lambda pi, pj, Lq, Lr: sw_fn(
+            ids_dev, lens_dev, pi, pj, Lq=Lq, Lr=Lr, **gaps)
+    if kind == "ungapped":
+        return lambda pi, pj, Lq, Lr: _wave_ungapped_device(
+            ids_dev, lens_dev, pi, pj, x=cfg.xdrop, Lq=Lq, Lr=Lr)
+    return lambda pi, pj, Lq, Lr: sw_gather_scores(
+        ids_dev, lens_dev, ids_dev, lens_dev, pi, pj, Lq=Lq, Lr=Lr, **gaps)
+
+
+def _run_score_waves(lens, pairs, subset, cfg: WaveConfig, dev, sink,
                      stats: _WaveStats, *, kind: str, wave_batch: int,
-                     use_pallas: bool, ndev: int = 1) -> None:
-    """Dispatch waves (``kind``: "sw" | "ungapped", or "pid" through the
-    Pallas PID kernel) over ``pairs[subset]``, handing each wave's results
-    to ``sink(slots, host)`` through the async drain ring. With
-    ``ndev > 1`` each wave is one SPMD program splitting its batch over
-    the mesh (``_sharded_wave_fns``)."""
+                     pallas: bool, ndev: int = 1) -> None:
+    """Dispatch waves of ``kind`` over ``pairs[subset]``, one `_wave_step`
+    call each, handing each wave's results to ``sink(slots, host)``
+    through the async drain ring."""
     sub = pairs[subset]
-    sharded = (_sharded_wave_fns(tuple(jax.devices()[:ndev]))
-               if ndev > 1 else None)
+    wave = _wave_step(kind, cfg, dev, pallas, ndev)
     ring = _DrainRing(0 if cfg.profile else cfg.inflight, sink, kind)
     for chunk, B, Lq, Lr in _iter_wave_chunks(sub, lens, cfg, wave_batch,
                                               ndev):
-        if dev is None:                     # host-gather (PR 2) path
-            with span("host_gather", cat="allpairs", B=B, n=len(chunk)):
-                qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
+        pi, pj = _pad_chunk(sub, chunk, B)
         # the span covers dispatch only: the device's work ends in the
         # ``drain`` span that fetches it, unless cfg.profile blocks here
         with span("wave", cat="allpairs", kind=kind, B=B, Lq=Lq, Lr=Lr,
                   n=len(chunk), spmd=ndev > 1):
-            if dev is None:
-                res = _score_block(qm, rm, kind, cfg.xdrop, use_pallas, cfg)
-            elif use_pallas:                # device gather -> Pallas tile
-                pi, pj = _pad_chunk(sub, chunk, B)
-                qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
-                                      jnp.asarray(pj), Lq=Lq, Lr=Lr)
-                res = _score_block(qm, rm, kind, cfg.xdrop, True, cfg)
-            elif sharded is not None:       # SPMD split over the mesh
-                pi, pj = _pad_chunk(sub, chunk, B)
-                sw_fn, ungapped_fn = sharded
-                if kind == "ungapped":
-                    res = ungapped_fn(dev[0], dev[1], pi, pj, x=cfg.xdrop,
-                                      Lq=Lq, Lr=Lr)
-                else:
-                    res = sw_fn(dev[0], dev[1], pi, pj, Lq=Lq, Lr=Lr,
-                                dp_kernel=cfg.dp_kernel,
-                                gap_mode=cfg.gap_mode,
-                                gap_open=cfg.gap_open,
-                                gap_extend=cfg.gap_extend)
-            elif kind == "ungapped":        # fused gather + scan
-                pi, pj = _pad_chunk(sub, chunk, B)
-                res = _wave_ungapped_device(dev[0], dev[1], pi, pj,
-                                            x=cfg.xdrop, Lq=Lq, Lr=Lr)
-            else:
-                pi, pj = _pad_chunk(sub, chunk, B)
-                res = sw_gather_scores(dev[0], dev[1], dev[0], dev[1],
-                                       pi, pj, Lq=Lq, Lr=Lr,
-                                       dp_kernel=cfg.dp_kernel,
-                                       gap_mode=cfg.gap_mode,
-                                       gap_open=cfg.gap_open,
-                                       gap_extend=cfg.gap_extend)
+            res = wave(pi, pj, Lq, Lr)
             if cfg.profile:
                 jax.block_until_ready(res)
         ring.push(subset[chunk], res, B)
@@ -399,22 +336,17 @@ def _run_score_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev, sink,
     ring.drain()
 
 
-def _run_pid_waves(ids, lens, pairs, subset, cfg: WaveConfig, dev,
+def _run_pid_waves(lens, pairs, subset, cfg: WaveConfig, dev,
                    scores, pid, aln, stats: _WaveStats) -> None:
     """Host PID route (off-TPU, the definition the kernel is tested
     against): the row wave's DP matrices, then the host traceback. The
-    traceback is host-bound, so this route drains synchronously; the
-    device gather still removes the per-pair copy loop."""
+    traceback is host-bound, so this route drains synchronously."""
     sub = pairs[subset]
     for chunk, B, Lq, Lr in _iter_wave_chunks(sub, lens, cfg,
                                               cfg.wave_batch):
-        if dev is None:
-            with span("host_gather", cat="allpairs", B=B, n=len(chunk)):
-                qm, rm = _host_gather(ids, lens, sub, chunk, B, Lq, Lr)
-        else:
-            pi, pj = _pad_chunk(sub, chunk, B)
-            qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
-                                  jnp.asarray(pj), Lq=Lq, Lr=Lr)
+        pi, pj = _pad_chunk(sub, chunk, B)
+        qm, rm = _gather_wave(dev[0], dev[1], jnp.asarray(pi),
+                              jnp.asarray(pj), Lq=Lq, Lr=Lr)
         # the whole PID wave: device DP + H-matrix D2H + host traceback
         # (sw_wave_pid interleaves them internally)
         with span("wave", cat="allpairs", kind="pid", B=B, Lq=Lq, Lr=Lr,
@@ -440,16 +372,11 @@ def score_pairs(ids: np.ndarray, lens: np.ndarray, pairs: np.ndarray,
     bound (and PID 0).
     """
     cfg = cfg or WaveConfig()
-    if cfg.dp_kernel not in ("wavefront", "rowwave"):
-        raise ValueError(f"unknown dp_kernel {cfg.dp_kernel!r}")
     if cfg.gap_mode not in ("linear", "affine"):
         raise ValueError(f"unknown gap_mode {cfg.gap_mode!r}")
-    if cfg.gap_mode == "affine":
-        if cfg.dp_kernel == "rowwave":
-            raise ValueError("affine gaps need dp_kernel='wavefront'")
-        if cfg.with_pid:
-            raise ValueError("with_pid needs gap_mode='linear' (the PID "
-                             "traceback reads the linear-gap DP matrix)")
+    if cfg.gap_mode == "affine" and cfg.with_pid:
+        raise ValueError("with_pid needs gap_mode='linear' (the PID "
+                         "traceback reads the linear-gap DP matrix)")
     pairs = np.asarray(pairs, np.int32)
     lens = np.asarray(lens, np.int32)
     P = len(pairs)
@@ -466,48 +393,41 @@ def _score_pairs(ids, lens, pairs, cfg: WaveConfig) -> PairScores:
     pid = np.zeros(P) if cfg.with_pid else None
     aln = np.zeros(P, np.int64) if cfg.with_pid else None
     stats = _WaveStats()
-    use_pallas = (cfg.use_pallas if cfg.use_pallas is not None
-                  else on_tpu())
-    dev = ((jnp.asarray(ids), jnp.asarray(lens))
-           if cfg.device_gather and P else None)
-    # SPMD wave split: only the jnp score/prefilter waves shard (the Pallas
-    # kernels and the host PID route stay single-device)
-    ndev = 1
-    if dev is not None and not use_pallas:
-        ndev = max(1, min(cfg.n_devices, jax.device_count()))
+    pallas = on_tpu()
+    dev = (jnp.asarray(ids), jnp.asarray(lens)) if P else None
+    # SPMD wave split: only the jnp score/prefilter waves shard
+    ndev = 1 if pallas else max(1, min(cfg.n_devices, jax.device_count()))
 
-    everything = np.arange(P)
     ungapped = None
     kept = None
-    subset = everything
+    subset = np.arange(P)
     if cfg.prefilter and P:
         ungapped = np.zeros(P, np.int32)
-        _run_score_waves(ids, lens, pairs, everything, cfg, dev,
-                         _store(ungapped), stats, kind="ungapped",
-                         wave_batch=cfg.prefilter_batch,
-                         use_pallas=use_pallas, ndev=ndev)
+        _run_score_waves(lens, pairs, subset, cfg, dev, _store(ungapped),
+                         stats, kind="ungapped",
+                         wave_batch=cfg.prefilter_batch, pallas=pallas,
+                         ndev=ndev)
         kept = ungapped >= cfg.prefilter_min
         scores[:] = ungapped        # lower bound for the rejected pairs
         subset = np.flatnonzero(kept)
     if len(subset):
-        if cfg.with_pid and use_pallas:
+        if cfg.with_pid and pallas:
             def sink(slots, host):
                 n = len(slots)
                 scores[slots] = host[:n, 0]
                 aln[slots] = host[:n, 2]
                 pid[slots] = 100.0 * host[:n, 1] / np.maximum(host[:n, 2], 1)
 
-            _run_score_waves(ids, lens, pairs, subset, cfg, dev, sink,
-                             stats, kind="pid", wave_batch=cfg.wave_batch,
-                             use_pallas=True)
+            _run_score_waves(lens, pairs, subset, cfg, dev, sink, stats,
+                             kind="pid", wave_batch=cfg.wave_batch,
+                             pallas=True)
         elif cfg.with_pid:
-            _run_pid_waves(ids, lens, pairs, subset, cfg, dev,
-                           scores, pid, aln, stats)
+            _run_pid_waves(lens, pairs, subset, cfg, dev, scores, pid, aln,
+                           stats)
         else:
-            _run_score_waves(ids, lens, pairs, subset, cfg, dev,
-                             _store(scores), stats, kind="sw",
-                             wave_batch=cfg.wave_batch,
-                             use_pallas=use_pallas, ndev=ndev)
+            _run_score_waves(lens, pairs, subset, cfg, dev, _store(scores),
+                             stats, kind="sw", wave_batch=cfg.wave_batch,
+                             pallas=pallas, ndev=ndev)
     return PairScores(scores=scores, pid=pid, aln_len=aln,
                       n_waves=stats.n_waves, n_shapes=len(stats.shapes),
                       ungapped=ungapped, kept=kept)

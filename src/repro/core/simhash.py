@@ -5,7 +5,6 @@ Two mathematically identical execution paths:
 * ``method="matmul"`` — the paper's structure on the MXU: per block of
   codebook words, score shingles against the word block (matmul), threshold
   at T, multiply by the ±1 hyperplane block H[w, :f] and accumulate V.
-  This is what ``kernels/siggen.py`` fuses into one Pallas kernel.
 
 * ``method="table"`` — beyond-paper: because the neighbour set and scores of
   a shingle depend only on its word id, the *total* contribution of a shingle

@@ -202,10 +202,8 @@ class ServingConfig:
     dense_threshold: int = 1024     # "auto": dense kernel below this size
     mode: str = "auto"              # "probe" | "dense" | "auto"
     rerank: bool = False            # Smith-Waterman re-rank of the top-k
-    dp_kernel: str = "wavefront"    # re-rank DP sweep: "wavefront" (anti-
-                                    # diagonal, no prefix scan) | "rowwave"
-    gap_mode: str = "linear"        # "linear" | "affine" (Gotoh; needs
-                                    # dp_kernel="wavefront")
+    gap_mode: str = "linear"        # re-rank gap model: "linear" |
+                                    # "affine" (Gotoh)
     gap_open: int | None = None     # affine defaults: BLOSUM62 -11 / -1
     gap_extend: int | None = None
 
@@ -488,8 +486,7 @@ class QueryEngine:
             jnp.asarray(np.asarray(lens, np.int32)),
             ref_ids_dev, ref_lens_dev, qv, rv,
             Lq=Lq, Lr=int(ref_ids_dev.shape[1]),
-            dp_kernel=self.cfg.dp_kernel, gap_mode=self.cfg.gap_mode,
-            gap_open=self.cfg.gap_open,
+            gap_mode=self.cfg.gap_mode, gap_open=self.cfg.gap_open,
             gap_extend=self.cfg.gap_extend))[:len(qi)]
         smat = np.full((B, K), -np.inf)
         smat[qi, ki] = scores

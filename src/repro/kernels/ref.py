@@ -11,19 +11,6 @@ def hamming_dist_ref(q, r) -> jnp.ndarray:
     return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
 
 
-def hamming_count_ref(q, r, d: int) -> jnp.ndarray:
-    """(Q, nw) x (R, nw) -> (Q, 1) int32 counts of refs within distance d."""
-    dist = hamming_dist_ref(q, r)
-    return jnp.sum((dist <= d).astype(jnp.int32), axis=-1, keepdims=True)
-
-
-def siggen_accumulate_ref(rows, cb, H, T: int) -> jnp.ndarray:
-    """(S, D) x (W, D) x (W, f) -> (S, f) int32 SimHash accumulators."""
-    scores = rows.astype(jnp.int32) @ cb.astype(jnp.int32).T   # (S, W)
-    wts = jnp.where(scores >= T, scores, 0)
-    return wts @ H.astype(jnp.int32)                           # (S, f)
-
-
 def sw_affine_ref(q, r, gap_open: int = -11, gap_extend: int = -1):
     """Host Gotoh oracle: best local alignment score of one encoded pair
     (unpadded int8 arrays) under affine gaps, walking every cell of the

@@ -1,6 +1,6 @@
 """Pallas TPU kernels: batched Smith-Waterman over a pair block — the
-anti-diagonal (wavefront) Gotoh sweep, its percent-identity mode, the
-ungapped X-drop prefilter, and the legacy row wave.
+anti-diagonal (wavefront) Gotoh sweep, its percent-identity mode, and the
+ungapped X-drop prefilter.
 
 The all-pairs tiler's inner loop (`repro.allpairs.tiles`): score a block of
 (query, reference) pairs in one program. The wavefront and ungapped
@@ -33,12 +33,12 @@ no gather.
 ``interpret`` defaults to *autodetect*: kernels lower natively wherever the
 backend supports Pallas TPU lowering and fall back to interpret mode only
 where it is unavailable (the CPU). Pass ``interpret=True/False`` to
-override (exposed as ``WaveConfig.pallas_interpret``).
+override.
 
 Cell values are integer: scores are bit-exact with the jnp wavefront sweep
 (`align.gotoh`), the jnp ungapped scan
-(`align.smith_waterman.ungapped_xdrop_scores`) and the row wave
-(`align.smith_waterman.sw_align_batch`).
+(`align.smith_waterman.ungapped_xdrop_scores`) and the per-pair row wave
+(`align.smith_waterman.sw_score`).
 """
 from __future__ import annotations
 
@@ -51,8 +51,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..align.gotoh import _BSENT, GAP_EXTEND, GAP_OPEN, SENT8
-from ..align.smith_waterman import GAP, NEG
-from ..core.alphabet import ALPHABET_SIZE, BLOSUM62_PADDED, PAD
+from ..align.smith_waterman import GAP
+from ..core.alphabet import ALPHABET_SIZE, PAD
 
 DEFAULT_BB = 8
 DIAG_BLOCK = 128        # anti-diagonals per grid step of the wave kernels
@@ -386,8 +386,8 @@ def wave_scores_kernel(qs, rs, *, gap_mode: str = "linear",
                        interpret: bool | None = None):
     """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores
     via the wavefront kernel. ``gap_mode="linear"`` (default gap = GAP) is
-    bit-exact with `sw_scores_kernel`; ``"affine"`` scores Gotoh gaps
-    (defaults -11/-1), bit-exact with `kernels.ref.sw_affine_ref`.
+    bit-exact with `align.smith_waterman.sw_score`; ``"affine"`` scores
+    Gotoh gaps (defaults -11/-1), bit-exact with `kernels.ref.sw_affine_ref`.
 
     B % bb == 0 is handled by padding in ops.wavefront_scores.
     """
@@ -433,72 +433,3 @@ def ungapped_scores_kernel(qs, rs, *, x: int | None, bb: int = DEFAULT_BB,
     segment); bit-exact with `align.smith_waterman.ungapped_xdrop_scores`."""
     return _wave_rows(qs, rs, _BSENT, mode="ungapped", bb=bb,
                       interpret=interpret, x=x)
-
-
-def _sw_kernel(qsub_ref, r_ref, out_ref, *, Lq: int):
-    """Row wave: scan query rows with `fori_loop`, keeping only the
-    previous DP row (bb, Lr+1) and the running best. Row i's substitution
-    slice arrives PAD-masked as ``qsub_ref[i]`` (bb, A+1), so the row
-    needs neither the query residue nor a dynamic slice; the within-row
-    gap dependency is the max-plus prefix scan H = cummax(A + c*t) - c*t,
-    as a log-doubling shifted max."""
-    r = r_ref[...].astype(jnp.int32)          # (bb, Lr)
-    bb, Lr = r.shape
-    c = jnp.int32(-GAP)
-    # iota, not arange: pallas kernels may not capture constant arrays
-    t = jax.lax.broadcasted_iota(jnp.int32, (1, Lr), 1) + 1  # (1, Lr)
-
-    def row_step(i, carry):
-        prev, best = carry                    # (bb, Lr+1), (bb, 1)
-        si = qsub_ref[i]                      # (bb, A+1) int32
-        # sub_row[b, j] = B[q[b, i], r[b, j]] via 21 selects (no gathers)
-        sub_row = jnp.zeros((bb, Lr), jnp.int32)
-        for a in range(ALPHABET_SIZE + 1):
-            sub_row = jnp.where(r == a, si[:, a:a + 1], sub_row)
-        a_row = jnp.maximum(0, jnp.maximum(prev[:, :-1] + sub_row,
-                                           prev[:, 1:] + GAP))
-        x = a_row + c * t
-        s = 1
-        while s < Lr:
-            shifted = jnp.concatenate(
-                [jnp.full((bb, s), jnp.int32(-2**31 + 1)), x[:, :-s]], axis=1)
-            x = jnp.maximum(x, shifted)
-            s *= 2
-        row_tail = x - c * t
-        row = jnp.concatenate([jnp.zeros((bb, 1), jnp.int32), row_tail],
-                              axis=1)
-        best = jnp.maximum(best, jnp.max(row, axis=1, keepdims=True))
-        return row, best
-
-    prev0 = jnp.zeros((bb, Lr + 1), jnp.int32)
-    best0 = jnp.zeros((bb, 1), jnp.int32)
-    _, best = jax.lax.fori_loop(0, Lq, row_step, (prev0, best0))
-    out_ref[...] = best
-
-
-@functools.partial(jax.jit, static_argnames=("bb", "interpret"))
-def sw_scores_kernel(qs, rs, *, bb: int = DEFAULT_BB,
-                     interpret: bool | None = None):
-    """(B, Lq) x (B, Lr) int8 pair block -> (B, 1) int32 best local scores
-    via the row wave. B % bb == 0 is handled by padding in
-    ops.sw_wave_scores. ``interpret=None`` autodetects (native lowering on
-    TPU)."""
-    B, Lq = qs.shape
-    Lr = rs.shape[1]
-    assert B % bb == 0, "pad the pair block to a bb multiple"
-    q = qs.astype(jnp.int32)
-    masked = (q == PAD)[..., None] | (
-        jnp.arange(ALPHABET_SIZE + 1) == PAD)
-    qsub = jnp.where(masked, NEG, jnp.asarray(BLOSUM62_PADDED)[q])
-    qsub = jnp.transpose(qsub, (1, 0, 2))     # (Lq, B, A+1) int32
-    return pl.pallas_call(
-        functools.partial(_sw_kernel, Lq=Lq),
-        grid=(B // bb,),
-        in_specs=[
-            pl.BlockSpec((Lq, bb, ALPHABET_SIZE + 1), lambda i: (0, i, 0)),
-            pl.BlockSpec((bb, Lr), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(qsub, rs)

@@ -2,7 +2,7 @@
 
   PYTHONPATH=src python -m repro.launch.allpairs \
       --n-families 64 --family-size 4 --n-singletons 256 --d 1 \
-      --min-pid 50 [--out /tmp/families.npz] [--pallas] [--stats] \
+      --min-pid 50 [--out /tmp/families.npz] [--score-only] [--stats] \
       [--incremental 128]
 
 Builds (or loads, --index) the corpus SignatureIndex, runs the LSH
@@ -65,41 +65,32 @@ def main(argv=None):
                     help="run the ungapped prefilter INSIDE the self-join "
                          "(rejected pairs never reach the host); same "
                          "thresholds as --prefilter, identical survivors")
-    ap.add_argument("--dp-kernel", default="wavefront",
-                    choices=["wavefront", "rowwave"],
-                    help="DP sweep for score-only waves: anti-diagonal "
-                         "wavefront (default; no within-row prefix scan) "
-                         "or the legacy row wave")
     ap.add_argument("--gap-mode", default="linear",
                     choices=["linear", "affine"],
                     help="gap model: linear (-4/residue) or affine Gotoh "
-                         "(open/extend; needs --dp-kernel wavefront and "
-                         "--pallas/--min-score scoring, PID waves stay "
-                         "linear)")
+                         "(open/extend; needs --score-only, PID waves "
+                         "stay linear)")
     ap.add_argument("--gap-open", type=int, default=None,
                     help="affine gap-open score (default -11)")
     ap.add_argument("--gap-extend", type=int, default=None,
                     help="affine gap-extend score (default -1)")
-    ap.add_argument("--host-gather", action="store_true",
-                    help="assemble waves with the host copy loop "
-                         "(PR 2 behaviour, for comparison)")
     ap.add_argument("--min-pid", type=float, default=50.0,
                     help="percent-identity threshold for family edges")
     ap.add_argument("--shards", type=int, default=1,
                     help="bucket shards: the self-join emits each shard's "
                          "buckets' pairs on its own device (mix32(key) %% "
-                         "n_shards ownership). Score-only waves (--pallas "
-                         "off + --prefilter's ungapped phase, or score "
-                         "thresholding) additionally split over that many "
-                         "devices as one SPMD program; PID waves (the "
-                         "default scoring mode here) stay single-device")
+                         "n_shards ownership). Off TPU, score-only waves "
+                         "(--score-only or --prefilter's ungapped phase) "
+                         "additionally split over that many devices as one "
+                         "SPMD program; PID waves (the default scoring "
+                         "mode here) and the TPU's Pallas waves stay "
+                         "single-device")
     ap.add_argument("--wave-batch", type=int, default=64)
-    ap.add_argument("--pallas", action="store_true",
-                    help="score waves with the Pallas SW tile kernel "
-                         "(turns off PID: families then threshold on "
-                         "SW score >= --min-score)")
+    ap.add_argument("--score-only", action="store_true",
+                    help="score waves without PID: families threshold on "
+                         "SW score >= --min-score")
     ap.add_argument("--min-score", type=int, default=60,
-                    help="SW score threshold used with --pallas")
+                    help="SW score threshold used with --score-only")
     ap.add_argument("--index", default=None,
                     help="reuse/persist the corpus index here (.npz = "
                          "legacy monolithic; otherwise a segment directory "
@@ -109,12 +100,6 @@ def main(argv=None):
                          "and ingest them afterwards via the delta "
                          "self-join + persistent family forest (families "
                          "equal the from-scratch recluster, at delta cost)")
-    ap.add_argument("--join-impl", default="spgemm",
-                    choices=["spgemm", "legacy"],
-                    help="candidate-generation orchestration: the fused "
-                         "device-resident masked-SpGEMM path (default) or "
-                         "the pre-SpGEMM host-merge path (identical pair "
-                         "arrays; kept one PR for comparison)")
     ap.add_argument("--out", default=None,
                     help="write edges + labels npz here")
     ap.add_argument("--stats", action="store_true",
@@ -180,19 +165,15 @@ def main(argv=None):
         min_pid=args.min_pid, min_score=args.min_score,
         n_shards=args.shards,
         wave=WaveConfig(wave_batch=args.wave_batch,
-                        use_pallas=args.pallas or None,
-                        with_pid=not args.pallas,
-                        device_gather=not args.host_gather,
+                        with_pid=not args.score_only,
                         n_devices=args.shards,
                         prefilter=args.prefilter,
                         prefilter_min=args.prefilter_min,
                         xdrop=args.xdrop,
-                        dp_kernel=args.dp_kernel,
                         gap_mode=args.gap_mode,
                         gap_open=args.gap_open,
                         gap_extend=args.gap_extend),
-        fuse_prefilter=args.fuse_prefilter,
-        join_impl=args.join_impl)
+        fuse_prefilter=args.fuse_prefilter)
 
     def _emit_metrics():
         from ..obs import REGISTRY, merge_registry_state, registry_state
@@ -280,11 +261,11 @@ def main(argv=None):
     print(f"[join]  {n} seqs -> {res.join.n_candidates} candidate pairs "
           f"({res.join.n_candidates / max(n*(n-1)//2, 1):.2%} of all pairs)")
     print(f"[score] {sc.n_waves} SW waves over {sc.n_shapes} fixed shapes"
-          f"{' (pallas)' if args.pallas else ''}"
+          f"{' (score-only)' if args.score_only else ''}"
           + (f"; prefilter rejected {sc.n_prefiltered}/{len(res.pairs)} "
              f"({sc.n_prefiltered / max(len(res.pairs), 1):.0%})"
              if sc.kept is not None else ""))
-    thresh = (f"SW score >= {args.min_score}" if args.pallas
+    thresh = (f"SW score >= {args.min_score}" if args.score_only
               else f"{args.min_pid:.0f}% PID")
     print(f"[graph] {int(res.families.edge_mask.sum())} edges at {thresh} "
           f"-> {res.families.n_families} families (total {wall:.2f}s)")

@@ -211,15 +211,10 @@ def main(argv=None):
                          "--index is rewritten as a single segment)")
     ap.add_argument("--rerank", action="store_true",
                     help="Smith-Waterman re-rank of the top-k")
-    ap.add_argument("--dp-kernel", default="wavefront",
-                    choices=["wavefront", "rowwave"],
-                    help="re-rank DP sweep (anti-diagonal wavefront is "
-                         "the default; rowwave is the legacy prefix-scan "
-                         "path)")
     ap.add_argument("--gap-mode", default="linear",
                     choices=["linear", "affine"],
-                    help="re-rank gap model; affine (Gotoh -11/-1) needs "
-                         "--dp-kernel wavefront")
+                    help="re-rank gap model: linear (-4/residue) or affine "
+                         "Gotoh (default -11/-1)")
     ap.add_argument("--gap-open", type=int, default=None,
                     help="affine gap-open score (default -11)")
     ap.add_argument("--gap-extend", type=int, default=None,
@@ -345,8 +340,8 @@ def main(argv=None):
 
     ref_seqs = (data["ref_ids"], data["ref_lens"])
     scfg = ServingConfig(k=args.k, max_batch=args.batch, rerank=args.rerank,
-                         dp_kernel=args.dp_kernel, gap_mode=args.gap_mode,
-                         gap_open=args.gap_open, gap_extend=args.gap_extend)
+                         gap_mode=args.gap_mode, gap_open=args.gap_open,
+                         gap_extend=args.gap_extend)
 
     if args.replicas >= 1:
         _serve_async(args, data, loaded, mesh, ref_seqs, scfg, path)

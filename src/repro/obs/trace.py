@@ -37,8 +37,7 @@ serve       submit, dispatch, shed, query_batch, ladder, sig, probe, ring,
             rerank, route, resolve, warmup
 lifecycle   seal, refresh, place, compact_serving, ingest, minor_compaction,
             major_compaction, compact_index, index_build
-allpairs    emission, delta_emission, wave, host_gather, drain, score_pairs,
-            graph
+allpairs    emission, delta_emission, wave, drain, score_pairs, graph
 jit         compile (instant; one per traced program body — see
             repro.obs.jit); lower (a JAX trace, lowering, backend compile
             or persistent-cache load, from ``jax.monitoring``)

@@ -149,9 +149,9 @@ class ReplicaFleet:
     straight into :class:`~repro.serve.engine.AsyncEngine`.
 
     One :class:`ServingConfig` governs every replica, including the
-    re-rank DP routing knobs (``dp_kernel``/``gap_mode``/``gap_open``/
-    ``gap_extend``): replicas share the process-wide jit cache, so the
-    gather+DP program of a given (rung, quantum, DP route) compiles once
+    re-rank gap knobs (``gap_mode``/``gap_open``/``gap_extend``):
+    replicas share the process-wide jit cache, so the gather+DP program
+    of a given (rung, quantum, gap model) compiles once
     for the whole fleet, and ``warmup()`` through any replica warms all.
     """
 

@@ -5,18 +5,19 @@ parity between the wave and the per-pair path)."""
 import numpy as np
 import pytest
 
-from repro.align.smith_waterman import (percent_identity, sw_align_batch,
-                                        sw_score, sw_wave_pid,
-                                        ungapped_xdrop_scores)
+from repro.align.smith_waterman import (GAP, percent_identity,
+                                        sw_align_batch, sw_score,
+                                        sw_wave_pid, ungapped_xdrop_scores)
 from repro.allpairs import (AllPairsConfig, WaveConfig, all_pairs_search,
                             brute_force_collisions, cluster_families,
                             lsh_self_join, score_pairs, union_find)
+from repro.allpairs import tiles
 from repro.allpairs.tiles import _iter_wave_chunks
 from repro.core import LSHConfig
 from repro.core.alphabet import PAD
 from repro.data import FamilyCorpusConfig, make_family_corpus
 from repro.index import SignatureIndex
-from repro.kernels.ref import ungapped_xdrop_ref
+from repro.kernels.ref import sw_affine_ref, ungapped_xdrop_ref
 
 CFG = LSHConfig(k=3, T=13, f=32, d=1)
 
@@ -175,28 +176,22 @@ def _random_pairs(corpus, m, seed):
 
 # ------------------------------------------------- device-resident pipeline
 def test_device_vs_host_gather_bitexact_ragged(corpus):
-    """Fused on-device gather == host copy loop on a ragged corpus, for
-    score-only, PID, and prefilter waves alike."""
+    """Ragged device waves equal the per-pair definitions on the unpadded
+    pairs, for score-only, PID, and prefilter waves alike."""
     ids, lens = corpus["ids"], corpus["lens"]
     assert len(set(lens.tolist())) > 1, "corpus must be ragged"
     pairs = _random_pairs(corpus, 32, 3)
-    host = score_pairs(ids, lens, pairs,
-                       WaveConfig(wave_batch=8, device_gather=False,
-                                  with_pid=True))
-    dev = score_pairs(ids, lens, pairs,
-                      WaveConfig(wave_batch=8, device_gather=True,
-                                 with_pid=True))
-    np.testing.assert_array_equal(host.scores, dev.scores)
-    np.testing.assert_array_equal(host.pid, dev.pid)
-    np.testing.assert_array_equal(host.aln_len, dev.aln_len)
-    hostp = score_pairs(ids, lens, pairs,
-                        WaveConfig(wave_batch=8, device_gather=False,
-                                   prefilter=True))
-    devp = score_pairs(ids, lens, pairs,
-                       WaveConfig(wave_batch=8, device_gather=True,
-                                  prefilter=True))
-    np.testing.assert_array_equal(hostp.ungapped, devp.ungapped)
-    np.testing.assert_array_equal(hostp.scores, devp.scores)
+    dev = score_pairs(ids, lens, pairs, WaveConfig(wave_batch=8,
+                                                   with_pid=True))
+    devp = score_pairs(ids, lens, pairs, WaveConfig(wave_batch=8,
+                                                    prefilter=True))
+    for row, (i, j) in enumerate(pairs):
+        q, r = ids[i, :lens[i]], ids[j, :lens[j]]
+        assert (dev.pid[row], dev.aln_len[row], dev.scores[row]) == \
+            percent_identity(q, r)
+        assert devp.ungapped[row] == ungapped_xdrop_ref(q, r, 1 << 30)
+        want = sw_score(q, r) if devp.kept[row] else devp.ungapped[row]
+        assert devp.scores[row] == want
 
 
 def test_max_wave_cells_forces_single_pair_waves(corpus):
@@ -304,7 +299,7 @@ def test_async_ring_depths_agree(corpus):
         np.testing.assert_array_equal(got.scores, base.scores)
 
 
-def test_wave_pallas_kernel_parity(corpus):
+def test_wave_pallas_kernel_parity(corpus, monkeypatch):
     """The Pallas tile kernel scores == the jnp wave on ragged real pairs."""
     rng = np.random.default_rng(2)
     ids, lens = corpus["ids"], corpus["lens"]
@@ -312,9 +307,60 @@ def test_wave_pallas_kernel_parity(corpus):
     pairs = np.stack([rng.integers(0, n, 10), rng.integers(0, n, 10)],
                      axis=1).astype(np.int32)
     a = score_pairs(ids, lens, pairs, WaveConfig(wave_batch=4))
-    b = score_pairs(ids, lens, pairs,
-                    WaveConfig(wave_batch=4, use_pallas=True))
+    monkeypatch.setattr(tiles, "on_tpu", lambda: True)
+    b = score_pairs(ids, lens, pairs, WaveConfig(wave_batch=4))
     np.testing.assert_array_equal(a.scores, b.scores)
+
+
+@pytest.fixture(scope="module")
+def ragged():
+    """Twelve sequences of ragged lengths 1..96 and 16 pairs over them,
+    self-pairs and a length-1 sequence included."""
+    rng = np.random.default_rng(21)
+    lens = rng.integers(1, 97, 12).astype(np.int32)
+    lens[3] = 1
+    ids = np.full((12, 96), PAD, np.int8)
+    base = rng.integers(0, 20, 96).astype(np.int8)
+    for n, L in enumerate(lens):     # related rows, so scores are not all 0
+        s = np.where(rng.random(96) < 0.3, rng.integers(0, 20, 96), base)
+        ids[n, :L] = np.roll(s, int(rng.integers(0, 8)))[:L]
+    pairs = np.stack([rng.integers(0, 12, 16), rng.integers(0, 12, 16)],
+                     axis=1).astype(np.int32)
+    pairs[0] = (5, 5)
+    pairs[1] = (3, 7)
+    return ids, lens, pairs
+
+
+_KINDS = {
+    "ungapped": WaveConfig(wave_batch=8, prefilter=True,
+                           prefilter_min=1 << 30, xdrop=20),
+    "sw_linear": WaveConfig(wave_batch=8),
+    "sw_affine": WaveConfig(wave_batch=8, gap_mode="affine"),
+    "pid": WaveConfig(wave_batch=8, with_pid=True),
+}
+
+
+@pytest.mark.parametrize("route", ["kernel", "jnp"])
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_wave_route_matches_oracle(ragged, monkeypatch, kind, route):
+    """Each wave kind on each route — the Pallas kernels (interpreted
+    here) and the jnp sweeps — scores every pair slot of a ragged corpus
+    as the host oracles of `kernels/ref.py` (and `percent_identity` for
+    PID) score the unpadded pair."""
+    ids, lens, pairs = ragged
+    monkeypatch.setattr(tiles, "on_tpu", lambda: route == "kernel")
+    res = score_pairs(ids, lens, pairs, _KINDS[kind])
+    for row, (i, j) in enumerate(pairs):
+        q, r = ids[i, :lens[i]], ids[j, :lens[j]]
+        if kind == "ungapped":
+            assert not res.kept[row]
+            assert res.scores[row] == ungapped_xdrop_ref(q, r, 20)
+        elif kind == "pid":
+            assert (res.pid[row], res.aln_len[row], res.scores[row]) == \
+                percent_identity(q, r)
+        else:
+            gaps = (-11, -1) if kind == "sw_affine" else (GAP, GAP)
+            assert res.scores[row] == sw_affine_ref(q, r, *gaps)[0]
 
 
 # ---------------------------------------------------------------- wave plan

@@ -1,15 +1,16 @@
 """Wavefront (anti-diagonal) Smith-Waterman: linear-gap bit-exactness vs
-the row wave, affine (Gotoh) bit-exactness vs the numpy oracle, the int16
-lane guard boundary, Pallas-kernel parity under interpret mode, routing
-validation, recompile-sentinel steadiness across rung x quantum, and the
-prefilter-fused self-join (survivors bit-exact with post-hoc filtering)."""
+the per-pair row wave (`sw_score`), affine (Gotoh) bit-exactness vs the
+numpy oracle, the int16 lane guard boundary, Pallas-kernel parity under
+interpret mode, gap-knob validation, recompile-sentinel steadiness across
+rung x quantum, and the prefilter-fused self-join (survivors bit-exact
+with post-hoc filtering)."""
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
 from repro.align import gotoh
 from repro.align.smith_waterman import (GAP, dp_scores_block,
-                                        sw_align_batch, sw_gather_scores,
+                                        sw_gather_scores, sw_score,
                                         ungapped_xdrop_scores)
 from repro.allpairs import (AllPairsConfig, JoinPrefilter, WaveConfig,
                             all_pairs_search, lsh_self_join, score_pairs)
@@ -39,6 +40,12 @@ def _ragged_block(rng, B, Lq, Lr, *, all_pad_rows=(), len1_rows=()):
     return qs, rs
 
 
+def _per_pair(qs, rs):
+    """Scores of the per-pair row wave (the definition) on each padded
+    row pair."""
+    return np.array([sw_score(q, r) for q, r in zip(qs, rs)], np.int32)
+
+
 @pytest.fixture(scope="module")
 def block():
     rng = np.random.default_rng(7)
@@ -47,11 +54,11 @@ def block():
 
 
 # --------------------------------------------------------------- linear
-def test_wave_linear_matches_rowwave(block):
-    """Diagonal sweep == row wave, bit-exact, on ragged blocks including
-    all-PAD and length-1 rows."""
+def test_wave_linear_matches_sw_score(block):
+    """Diagonal sweep == per-pair row wave, bit-exact, on ragged blocks
+    including all-PAD and length-1 rows."""
     qs, rs = block
-    want = sw_align_batch(qs, rs)
+    want = _per_pair(qs, rs)
     got = np.asarray(gotoh.sw_wave_linear(qs, rs))
     np.testing.assert_array_equal(got, want)
 
@@ -62,7 +69,7 @@ def test_wave_linear_empty_and_singleton():
     qs[1, 0] = 5
     rs[1, 0] = 5
     got = np.asarray(gotoh.sw_wave_linear(qs, rs))
-    np.testing.assert_array_equal(got, sw_align_batch(qs, rs))
+    np.testing.assert_array_equal(got, _per_pair(qs, rs))
     assert got[0] == 0          # all-PAD pair scores exactly 0
 
 
@@ -72,7 +79,7 @@ def test_wave_linear_odd_diagonal_count():
     rng = np.random.default_rng(11)
     qs, rs = _ragged_block(rng, 8, 7, 6)
     np.testing.assert_array_equal(np.asarray(gotoh.sw_wave_linear(qs, rs)),
-                                  sw_align_batch(qs, rs))
+                                  _per_pair(qs, rs))
 
 
 # --------------------------------------------------------------- affine
@@ -91,7 +98,7 @@ def test_wave_affine_open_eq_extend_degenerates_to_linear(block):
     qs, rs = block
     got = np.asarray(gotoh.sw_wave_affine(qs, rs, gap_open=GAP,
                                           gap_extend=GAP))
-    np.testing.assert_array_equal(got, sw_align_batch(qs, rs))
+    np.testing.assert_array_equal(got, _per_pair(qs, rs))
 
 
 def test_affine_never_exceeds_linear_at_same_open(block):
@@ -129,16 +136,14 @@ def test_wave_scores_exact_across_lane_dtype():
 # ------------------------------------------------------------- routing
 def test_dp_scores_block_routes_and_validates(block):
     qs, rs = block
-    lin_row = np.asarray(dp_scores_block(qs, rs, dp_kernel="rowwave"))
-    lin_wave = np.asarray(dp_scores_block(qs, rs, dp_kernel="wavefront"))
-    np.testing.assert_array_equal(lin_row, lin_wave)
+    lin = np.asarray(dp_scores_block(qs, rs))
+    np.testing.assert_array_equal(lin, _per_pair(qs, rs))
+    lin11 = np.asarray(dp_scores_block(qs, rs, gap_open=-11))
+    np.testing.assert_array_equal(lin11, np.asarray(
+        gotoh.sw_wave_linear(qs, rs, gap=-11)))
     aff = np.asarray(dp_scores_block(qs, rs, gap_mode="affine"))
     np.testing.assert_array_equal(aff, np.asarray(
         gotoh.sw_wave_affine(qs, rs)))
-    with pytest.raises(ValueError, match="wavefront"):
-        dp_scores_block(qs, rs, dp_kernel="rowwave", gap_mode="affine")
-    with pytest.raises(ValueError, match="dp_kernel"):
-        dp_scores_block(qs, rs, dp_kernel="zigzag")
     with pytest.raises(ValueError, match="gap_mode"):
         dp_scores_block(qs, rs, gap_mode="convex")
 
@@ -147,14 +152,11 @@ def test_score_pairs_validates_knobs(block):
     ids = np.asarray(block[0])
     lens = (ids != PAD).sum(axis=1).astype(np.int32)
     pairs = np.array([[0, 1]], np.int32)
-    with pytest.raises(ValueError, match="wavefront"):
-        score_pairs(ids, lens, pairs, WaveConfig(dp_kernel="rowwave",
-                                                 gap_mode="affine"))
     with pytest.raises(ValueError, match="with_pid"):
         score_pairs(ids, lens, pairs, WaveConfig(gap_mode="affine",
                                                  with_pid=True))
-    with pytest.raises(ValueError, match="dp_kernel"):
-        score_pairs(ids, lens, pairs, WaveConfig(dp_kernel="zigzag"))
+    with pytest.raises(ValueError, match="gap_mode"):
+        score_pairs(ids, lens, pairs, WaveConfig(gap_mode="convex"))
 
 
 # ------------------------------------------------------- Pallas kernel
@@ -171,8 +173,9 @@ def test_pallas_wavefront_kernel_parity(gap_mode, B, Lq, Lr):
     qs, rs = _ragged_block(rng, B, Lq, Lr, all_pad_rows=(4,),
                            len1_rows=(6,))
     got = np.asarray(ops.wavefront_scores(qs, rs, gap_mode=gap_mode))
-    want = np.asarray(ops.wavefront_scores(qs, rs, gap_mode=gap_mode,
-                                           prefer_ref=True))
+    sweep = (gotoh.sw_wave_affine if gap_mode == "affine"
+             else gotoh.sw_wave_linear)
+    want = np.asarray(sweep(qs, rs))
     np.testing.assert_array_equal(got, want)
 
 

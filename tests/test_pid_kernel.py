@@ -4,14 +4,12 @@ host definition: the row wave's DP matrix walked back by
 for bit, including where the walk's argmax and predecessor rules meet
 ties, and the all-pairs scheduler's Pallas PID route must give the same
 pairs, PIDs and families as its host route."""
-from dataclasses import replace
-
 import numpy as np
 import pytest
 
 from repro.align.smith_waterman import percent_identity, sw_wave_pid
 from repro.allpairs import (AllPairsConfig, WaveConfig, all_pairs_search,
-                            score_pairs)
+                            score_pairs, tiles)
 from repro.core import LSHConfig
 from repro.core.alphabet import PAD, encode
 from repro.data import FamilyCorpusConfig, make_family_corpus
@@ -128,31 +126,33 @@ def corpus():
 
 @pytest.mark.parametrize("wave", [
     WaveConfig(wave_batch=16, with_pid=True),
-    WaveConfig(wave_batch=16, with_pid=True, device_gather=False),
+    # a finer ladder: many shapes, each wave's lengths ragged under it
+    WaveConfig(wave_batch=16, with_pid=True, len_quantum=32),
     WaveConfig(wave_batch=16, with_pid=True, prefilter=True,
-               prefilter_min=40)], ids=["device", "host_gather",
-                                        "prefilter"])
-def test_score_pairs_pallas_pid_matches_host_route(corpus, wave):
+               prefilter_min=40)], ids=["device", "ragged", "prefilter"])
+def test_score_pairs_pallas_pid_matches_host_route(corpus, monkeypatch,
+                                                   wave):
     ids, lens = corpus["ids"], corpus["lens"]
     rng = np.random.default_rng(3)
     n = len(lens)
     pairs = np.stack([rng.integers(0, n, 40), rng.integers(0, n, 40)],
                      axis=1).astype(np.int32)
-    host = score_pairs(ids, lens, pairs, replace(wave, use_pallas=False))
-    kern = score_pairs(ids, lens, pairs, replace(wave, use_pallas=True))
+    host = score_pairs(ids, lens, pairs, wave)
+    monkeypatch.setattr(tiles, "on_tpu", lambda: True)
+    kern = score_pairs(ids, lens, pairs, wave)
     np.testing.assert_array_equal(kern.scores, host.scores)
     np.testing.assert_array_equal(kern.pid, host.pid)
     np.testing.assert_array_equal(kern.aln_len, host.aln_len)
     assert (kern.n_waves, kern.n_shapes) == (host.n_waves, host.n_shapes)
 
 
-def test_all_pairs_search_pid_routes_agree(corpus):
+def test_all_pairs_search_pid_routes_agree(corpus, monkeypatch):
     ids, lens = corpus["ids"], corpus["lens"]
-    cfg = AllPairsConfig(lsh=LSHConfig(k=3, T=13, f=32, d=1))
-    host = all_pairs_search(ids, lens, replace(
-        cfg, wave=WaveConfig(with_pid=True, use_pallas=False)))
-    kern = all_pairs_search(ids, lens, replace(
-        cfg, wave=WaveConfig(with_pid=True, use_pallas=True)))
+    cfg = AllPairsConfig(lsh=LSHConfig(k=3, T=13, f=32, d=1),
+                         wave=WaveConfig(with_pid=True))
+    host = all_pairs_search(ids, lens, cfg)
+    monkeypatch.setattr(tiles, "on_tpu", lambda: True)
+    kern = all_pairs_search(ids, lens, cfg)
     assert len(host.pairs) > 0
     np.testing.assert_array_equal(kern.pairs, host.pairs)
     np.testing.assert_array_equal(kern.scored.scores, host.scored.scores)

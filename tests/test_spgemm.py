@@ -3,10 +3,9 @@ self-join, delta-join, and probe (``repro.index.spgemm``).
 
 The contract under test: bucket slabs are CSRs of a sequence×bucket
 incidence matrix A, candidates are masks over the semiring AᵀA, and the
-two orchestrations behind ``join_impl=`` — the fused device-resident
-SpGEMM path and the legacy host-merge + grow-and-retry path — produce
-BIT-IDENTICAL result arrays across shard counts, segment layouts, Hamming
-filters, and the flip layout; the probe is a row slice of the same
+fused device-resident join equals the brute-force collision oracle
+(Hamming-filtered on the host for ``d``) across shard counts, segment
+layouts and the flip layout; the probe is a row slice of the same
 product; warmed joins never retrace; and the wider-f (64/128) folded band
 keys keep the join and probe exact.
 """
@@ -16,6 +15,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro.align.smith_waterman import ungapped_xdrop_scores
 from repro.allpairs import (JoinPrefilter, brute_force_collisions,
                             lsh_delta_join, lsh_self_join)
 from repro.core import LSHConfig
@@ -47,62 +47,66 @@ def index(corpus):
     return SignatureIndex.build(CFG, corpus["ids"], corpus["lens"])
 
 
-# ----------------------------------------------- join_impl equivalence grid
+# ------------------------------------------------ join vs the collision oracle
+def _oracle_pairs(index, d=None) -> np.ndarray:
+    """The brute-force band collisions, kept at packed Hamming distance
+    <= ``d`` (numpy popcount) when given, as sorted (P, 2) int32 rows."""
+    want = sorted(brute_force_collisions(index))
+    p = np.array(want, np.int64).reshape(-1, 2)
+    if d is not None and len(p):
+        sigs = np.asarray(index.sigs)
+        dist = np.bitwise_count(sigs[p[:, 0]] ^ sigs[p[:, 1]]).sum(axis=1)
+        p = p[dist <= d]
+    return p.astype(np.int32)
+
+
 @pytest.mark.parametrize("n_shards", [1, 2, 4])
 @pytest.mark.parametrize("d_filter", [None, CFG.d])
-def test_join_impl_equivalence_grid(index, n_shards, d_filter):
-    """legacy and spgemm orchestrations return bit-identical arrays for
-    every (n_shards, d) cell, and the unfiltered set is the brute-force
-    collision oracle."""
-    legacy = lsh_self_join(index, d=d_filter, n_shards=n_shards,
-                           join_impl="legacy")
-    fused = lsh_self_join(index, d=d_filter, n_shards=n_shards,
-                          join_impl="spgemm")
-    np.testing.assert_array_equal(legacy.pairs, fused.pairs)
-    np.testing.assert_array_equal(legacy.indptr, fused.indptr)
-    assert legacy.n_candidates == fused.n_candidates
-    if d_filter is None:
-        assert {tuple(p) for p in fused.pairs} == \
-            brute_force_collisions(index)
+def test_join_equivalence_grid(index, n_shards, d_filter):
+    """The join returns the oracle's pair set as sorted arrays with a
+    matching CSR for every (n_shards, d) cell."""
+    fused = lsh_self_join(index, d=d_filter, n_shards=n_shards)
+    want = _oracle_pairs(index, d_filter)
+    np.testing.assert_array_equal(fused.pairs, want)
+    np.testing.assert_array_equal(
+        fused.indptr, np.searchsorted(want[:, 0], np.arange(index.size + 1)))
+    assert fused.n_candidates == len(want)
 
 
-def test_join_impl_flip_layout(corpus):
+def test_join_flip_layout(corpus):
     """The flip layout (each signature in C(f,<=d) buckets of ONE band)
     exercises the dedup pack — a pair can collide many times within the
     single band, so the keyed dup-free path must gate itself off."""
     idx = SignatureIndex.build(CFG, corpus["ids"], corpus["lens"],
                                layout="flip")
-    legacy = lsh_self_join(idx, join_impl="legacy")
-    fused = lsh_self_join(idx, join_impl="spgemm")
-    np.testing.assert_array_equal(legacy.pairs, fused.pairs)
-    assert {tuple(p) for p in fused.pairs} == brute_force_collisions(idx)
+    fused = lsh_self_join(idx)
+    np.testing.assert_array_equal(fused.pairs, _oracle_pairs(idx))
 
 
-def test_join_impl_grow_caps(index):
-    """A tiny starting capacity converges identically under both impls
-    (legacy grows-and-retries; spgemm sizes the output exactly), and a
-    max_grow below true demand raises for both — never a silent cap."""
+def test_join_grow_caps(index):
+    """A tiny starting capacity converges to the oracle (the output is
+    sized at the exact emission total), and a max_grow below true demand
+    raises — never a silent cap."""
     full = lsh_self_join(index, max_pairs=1 << 16)
-    for impl in ("legacy", "spgemm"):
-        small = lsh_self_join(index, max_pairs=2, join_impl=impl)
-        np.testing.assert_array_equal(small.pairs, full.pairs)
-        with pytest.raises(RuntimeError, match="max_grow"):
-            lsh_self_join(index, max_pairs=2, max_grow=2, join_impl=impl)
-        # max_grow caps GROWTH, not the count: the unique pair count here
-        # (119) exceeds the per-band emission max (69), yet with a roomy
-        # max_pairs legacy never grows its buffer and so never raises —
-        # spgemm must mirror that exactly
-        need = int(index.partition(1).pair_totals.max())
-        assert need < len(full.pairs)
-        big = lsh_self_join(index, max_pairs=1 << 16, max_grow=need,
-                            join_impl=impl)
-        np.testing.assert_array_equal(big.pairs, full.pairs)
+    np.testing.assert_array_equal(full.pairs, _oracle_pairs(index))
+    small = lsh_self_join(index, max_pairs=2)
+    np.testing.assert_array_equal(small.pairs, full.pairs)
+    with pytest.raises(RuntimeError, match="max_grow"):
+        lsh_self_join(index, max_pairs=2, max_grow=2)
+    # max_grow caps GROWTH, not the count: the unique pair count here
+    # (119) exceeds the per-band emission max (69), yet a roomy max_pairs
+    # already covers it, so the join must not raise
+    need = int(index.partition(1).pair_totals.max())
+    assert need < len(full.pairs)
+    big = lsh_self_join(index, max_pairs=1 << 16, max_grow=need)
+    np.testing.assert_array_equal(big.pairs, full.pairs)
 
 
 @pytest.mark.parametrize("n_shards", [1, 2, 4])
-def test_delta_join_impl_equivalence(corpus, n_shards):
+def test_delta_join_equivalence(corpus, n_shards):
     """Multi-segment delta join: per-shard cross emission under the bucket
-    partition is bit-exact vs the from-scratch join, for both impls."""
+    partition unions with the resident pairs to exactly the from-scratch
+    join, which is the oracle's pair set."""
     ids, lens = corpus["ids"], corpus["lens"]
     n = len(lens)
     base = n - 24
@@ -110,24 +114,31 @@ def test_delta_join_impl_equivalence(corpus, n_shards):
     old = lsh_self_join(idx)
     for a, b in ((base, n - 12), (n - 12, n)):      # two sealed segments
         idx.add(ids[a:b], lens[a:b])
-    deltas = [lsh_delta_join(idx, base_size=base, n_shards=n_shards,
-                             join_impl=impl)
-              for impl in ("legacy", "spgemm")]
-    np.testing.assert_array_equal(deltas[0].pairs, deltas[1].pairs)
-    full = lsh_self_join(SignatureIndex.build(CFG, ids, lens))
-    union = np.concatenate([old.pairs, deltas[1].pairs], axis=0)
+    delta = lsh_delta_join(idx, base_size=base, n_shards=n_shards)
+    assert (delta.pairs[:, 1] >= base).all()        # every pair touches new
+    grown = SignatureIndex.build(CFG, ids, lens)
+    full = lsh_self_join(grown)
+    np.testing.assert_array_equal(full.pairs, _oracle_pairs(grown))
+    union = np.concatenate([old.pairs, delta.pairs], axis=0)
     union = union[np.lexsort((union[:, 1], union[:, 0]))]
     np.testing.assert_array_equal(union, full.pairs)
 
 
 def test_prefilter_fused_identical_across_impls(corpus, index):
-    pf = JoinPrefilter(ids=corpus["ids"], lens=corpus["lens"],
-                       min_score=20)
-    legacy = lsh_self_join(index, prefilter=pf, join_impl="legacy")
-    fused = lsh_self_join(index, prefilter=pf, join_impl="spgemm")
-    np.testing.assert_array_equal(legacy.pairs, fused.pairs)
-    np.testing.assert_array_equal(legacy.ungapped, fused.ungapped)
-    assert legacy.n_prefiltered == fused.n_prefiltered
+    """The in-join prefilter keeps exactly the candidates whose post-hoc
+    ungapped score (`ungapped_xdrop_scores` on the unpadded pair) reaches
+    ``min_score``, with those scores."""
+    ids, lens = corpus["ids"], corpus["lens"]
+    pf = JoinPrefilter(ids=ids, lens=lens, min_score=20)
+    fused = lsh_self_join(index, prefilter=pf)
+    join = lsh_self_join(index)
+    ung = np.array([int(np.asarray(ungapped_xdrop_scores(
+        ids[None, i, :lens[i]], ids[None, j, :lens[j]]))[0])
+        for i, j in join.pairs])
+    keep = ung >= pf.min_score
+    np.testing.assert_array_equal(fused.pairs, join.pairs[keep])
+    np.testing.assert_array_equal(fused.ungapped, ung[keep])
+    assert fused.n_prefiltered == int((~keep).sum())
 
 
 # ------------------------------------------------- probe = row slice of AᵀA
@@ -249,19 +260,17 @@ def test_emission_route_budget(monkeypatch, tpu, U1, E, cap, route):
 
 # ------------------------------------------------------- recompile sentinel
 def test_spgemm_steady_state_no_recompiles(index):
-    """Warmed joins retrace nothing: the fused keyed program, the dedup
-    pack, and the legacy orchestration all hit their jit caches on every
+    """Warmed joins retrace nothing: the fused keyed program and the
+    per-shard emission + dedup pack hit their jit caches on every
     subsequent call."""
     for _ in range(2):                                 # warm every program
-        for impl in ("legacy", "spgemm"):
-            for ns in (1, 2):
-                lsh_self_join(index, n_shards=ns, join_impl=impl)
+        for ns in (1, 2):
+            lsh_self_join(index, n_shards=ns)
     for site in ("spgemm_join_keys", "spgemm_self", "spgemm_pack"):
         assert SENTINEL.total(site) >= 1, f"site {site} never traced"
     with SENTINEL.expect_no_compiles(message="warmed self-join retraced"):
-        for impl in ("legacy", "spgemm"):
-            for ns in (1, 2):
-                lsh_self_join(index, n_shards=ns, join_impl=impl)
+        for ns in (1, 2):
+            lsh_self_join(index, n_shards=ns)
 
 
 # ------------------------------------------------------------ wider-f (64+)
